@@ -11,10 +11,10 @@ tet_map over every launched tet lambda vs the BB-3D div/mod + simplex
 guard over every launched cube lambda, plus the 3-body triplet kernel
 (scan impls) at small scale.
 
-On an accelerator backend, --accelerator times the REAL Pallas tet kernel
-(interpret=False, block=128, production scale) against the BB-3D Pallas
-baseline instead of the scan-at-toy-scale stand-ins; on CPU the flag
-falls back to the scan impls with a note (ROADMAP open item).
+--accelerator times the REAL Pallas tet kernel (compiled, block=128,
+production scale) against the BB-3D Pallas baseline instead of the
+scan-at-toy-scale stand-ins. It needs a TPU: without one it exits non-zero
+rather than time a stand-in under the kernel's name.
 
   PYTHONPATH=src python -m benchmarks.bench_tet_mapping [--accelerator]
 """
@@ -78,32 +78,27 @@ def kernel_run(n_rows: int = 32, block: int = 8, d: int = 4, *,
     """3-body triplet reduction wall-clock.
 
     Default: tet scan vs BB-3D scan at toy scale (CPU-friendly).
-    accelerator=True on a non-CPU backend: the real Pallas kernels with
-    interpret=False and block=128 at production tile counts — the numbers
-    that actually validate the launch-reduction claim on hardware.
+    accelerator=True: the real Pallas kernels, compiled, with block=128 at
+    production tile counts — the numbers that actually validate the
+    launch-reduction claim on hardware. Raises SystemExit off a TPU.
     """
     from repro.kernels.tri_3body import ops as OPS
 
     backend = jax.default_backend()
-    on_hw = accelerator and backend != "cpu"
-    if accelerator and not on_hw:
-        print(f"--accelerator requested but backend is {backend!r}; "
-              "falling back to scan impls at toy scale")
-    if on_hw:
+    if accelerator:
+        if backend != "tpu":
+            raise SystemExit(f"--accelerator needs a TPU; JAX found "
+                             f"{backend!r}")
         block = 128
         n_rows = 16 * block  # n = 16 tiles/side: tet 816 vs bb3 4096 tiles
         d = max(d, 64)
         impls = ("pallas", "bb3")
-        interpret = False
     else:
         impls = ("scan", "bb3_scan")
-        interpret = True
 
     x = jax.random.normal(jax.random.PRNGKey(0), (n_rows, d), jnp.float32)
-    tet_fn = jax.jit(lambda v: OPS.three_body(
-        v, block, impl=impls[0], interpret=interpret))
-    bb3_fn = jax.jit(lambda v: OPS.three_body(
-        v, block, impl=impls[1], interpret=interpret))
+    tet_fn = jax.jit(lambda v: OPS.three_body(v, block, impl=impls[0]))
+    bb3_fn = jax.jit(lambda v: OPS.three_body(v, block, impl=impls[1]))
     t_tet = _time(tet_fn, x)
     t_bb3 = _time(bb3_fn, x)
     n = n_rows // block
@@ -117,8 +112,8 @@ def kernel_run(n_rows: int = 32, block: int = 8, d: int = 4, *,
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--accelerator", action="store_true",
-                    help="time the Pallas tet kernel with interpret=False "
-                         "and block=128 (needs a non-CPU backend)")
+                    help="time the compiled Pallas tet kernel with "
+                         "block=128 (needs a TPU; exits non-zero without)")
     args = ap.parse_args(argv)
     rows = run(out_path="artifacts/bench_tet_mapping.json")
     print(f"{'N':>6} {'tet':>10} {'bb3':>11} {'waste%':>7} {'reduce':>7} "

@@ -40,6 +40,9 @@ def main(argv=None):
                          "(scripts/check.sh tier)")
     args = ap.parse_args(argv)
     os.makedirs("artifacts", exist_ok=True)
+    from repro.launch.compile_cache import place_compile_cache
+
+    place_compile_cache()
 
     from repro.obs import launch as OBS_LAUNCH
     from repro.obs import sinks as SK

@@ -7,9 +7,10 @@ from repro.launch import serve
 
 
 def main():
-    results = serve.main(["--arch", "mixtral-8x7b", "--requests", "6",
-                          "--slots", "3", "--max-new", "12",
-                          "--max-len", "64"])
+    engine = serve.main(["--arch", "mixtral-8x7b", "--requests", "6",
+                         "--slots", "3", "--max-new", "12",
+                         "--max-len", "64"])
+    results = {r.uid: r.out for r in engine.finished}
     assert len(results) == 6
     assert all(len(v) == 12 for v in results.values())
     print("serve_demo OK")

@@ -1,7 +1,6 @@
 #!/usr/bin/env bash
 # Tier-1 verification, exactly as the roadmap specifies, plus the offline
-# guard: the suite must collect and pass with no network and no optional
-# deps (hypothesis is shimmed by tests/_hypo_compat.py when absent).
+# guard: the suite must collect and pass with no network.
 #
 #   scripts/check.sh            # tier-1 + no-network guard + bench smoke
 #   scripts/check.sh -k tet     # extra args forwarded to pytest

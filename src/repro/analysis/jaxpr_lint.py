@@ -20,6 +20,9 @@ and enforces the launch-structure invariants the runtime tests cannot see:
   capacity bucketing   decode grids must be power-of-two capacities
                        (recompile-hazard detection) and the decode launch
                        must carry the b+1-row output (pad garbage row).
+  platform resolution  every launch is a platform-dependent pair whose TPU
+                       branch is the compiled kernel: no TPU program can
+                       reach the Pallas interpreter.
   dtype hygiene        no f64/i64 avals anywhere in any traced jaxpr — an
                        accidental promotion doubles scalar-core latency
                        and memory traffic silently.
@@ -62,13 +65,52 @@ def _subjaxprs(value):
             yield from _subjaxprs(v)
 
 
+def _tpu_branch(eqn):
+    """The branch a TPU runs of a ``jax.lax.platform_dependent`` cond
+    (obs.launch wraps every Pallas launch in one), else None."""
+    platforms = eqn.params.get("branches_platforms")
+    if eqn.primitive.name != "cond" or platforms is None:
+        return None
+    for branch, ps in zip(eqn.params["branches"], platforms):
+        if ps is None or "tpu" in ps:
+            return branch.jaxpr
+    return None
+
+
 def iter_eqns(jaxpr):
-    """Depth-first over all equations, descending into sub-jaxprs."""
+    """Depth-first over all equations, descending into sub-jaxprs. Of a
+    platform-dependent cond only the TPU branch is walked: counts and
+    layouts are those of the program the chip runs (the interpreted
+    twin of each launch is a CPU-only stand-in)."""
     for eqn in jaxpr.eqns:
         yield eqn
+        tpu = _tpu_branch(eqn)
+        if tpu is not None:
+            yield from iter_eqns(tpu)
+            continue
         for v in eqn.params.values():
             for sub in _subjaxprs(v):
                 yield from iter_eqns(sub)
+
+
+def interpreted_on_tpu(jaxpr) -> int:
+    """pallas_calls the TPU branch would run in interpret mode, plus any
+    pallas_call outside a platform-dependent cond (which would run the
+    same mode on every platform). Must be 0."""
+    bad = 0
+    for eqn in jaxpr.eqns:
+        tpu = _tpu_branch(eqn)
+        if tpu is not None:
+            bad += sum(1 for e in iter_eqns(tpu)
+                       if e.primitive.name == "pallas_call"
+                       and e.params.get("interpret"))
+            continue
+        if eqn.primitive.name == "pallas_call":
+            bad += 1
+        for v in eqn.params.values():
+            for sub in _subjaxprs(v):
+                bad += interpreted_on_tpu(sub)
+    return bad
 
 
 def count_primitive(jaxpr, name: str) -> int:
@@ -164,6 +206,16 @@ def lint_packed_prefill() -> List[CheckResult]:
         f"dkv), {n_scan} scan/while (expect 0 — no silent autodiff "
         f"fallback)"))
 
+    # per-row softmax stats travel as (B, H, S, 1) f32: a (1, 1, blk)
+    # block of a (B, H, S) array is refused by Mosaic
+    pcs = find_eqns(fwd, "pallas_call")
+    b_, h_, s_, _ = q.shape
+    stat_shapes = [tuple(v.aval.shape) for e in pcs for v in e.outvars]
+    out.append(_res(
+        "jaxpr.packed_prefill.row_stats_layout",
+        (b_, h_, s_, 1) in stat_shapes,
+        f"packed fwd out avals {stat_shapes} must include the lse column "
+        f"{(b_, h_, s_, 1)}"))
     out.extend(_table_rules_packed(psched))
     out.append(_res(
         "jaxpr.packed_prefill.no_wide_dtypes", not wide_dtypes(grad),
@@ -231,16 +283,17 @@ def lint_packed_decode() -> List[CheckResult]:
         f"pallas decode: {len(pcs)} pallas_call (expect 1), "
         f"{count_primitive(jx, 'scan')} scan (expect 0)"))
 
-    # pad garbage row: the launch writes (b+1, h, d); row b belongs to the
-    # pad member and is dropped by the caller.
+    # pad garbage row: the launch writes (b+1, h, 1, d) — a (1, d) tile
+    # per query head, the layout Mosaic tiles; row b belongs to the pad
+    # member and is dropped by the caller.
     b, h, d = q.shape
     pad_row_ok = bool(pcs) and any(
-        tuple(v.aval.shape) == (b + 1, h, d) for v in pcs[0].outvars)
+        tuple(v.aval.shape) == (b + 1, h, 1, d) for v in pcs[0].outvars)
     out.append(_res(
         "jaxpr.packed_decode.pad_garbage_row", pad_row_ok,
         f"decode launch out avals "
         f"{[tuple(v.aval.shape) for v in pcs[0].outvars] if pcs else []} "
-        f"must include (b+1, h, d) = {(b + 1, h, d)}"))
+        f"must include (b+1, h, 1, d) = {(b + 1, h, 1, d)}"))
 
     # capacity bucketing: static grid is a power of two >= needed
     cap = spec.capacity
@@ -336,10 +389,10 @@ def lint_fused_step() -> List[CheckResult]:
     shapes = ([tuple(v.aval.shape) for v in pcs[0].outvars] if pcs else [])
     out.append(_res(
         "jaxpr.fused_step.garbage_outputs",
-        (1, h, s_pack + blk, d) in shapes and (b + 1, h, d) in shapes,
+        (1, h, s_pack + blk, d) in shapes and (b + 1, h, 1, d) in shapes,
         f"fused launch out avals {shapes} must include the pack buffer "
         f"with its garbage tile {(1, h, s_pack + blk, d)} AND the decode "
-        f"buffer with its pad row {(b + 1, h, d)}"))
+        f"buffer with its pad row {(b + 1, h, 1, d)}"))
 
     # (8, R) fused table ABI: kind row partitions prefill columns (0)
     # before decode columns (1); starts cumulative from 0; the shared pad
@@ -396,6 +449,55 @@ def lint_fused_step() -> List[CheckResult]:
     return out
 
 
+def lint_compiled_on_tpu() -> List[CheckResult]:
+    """Every Pallas launch of every public op is platform-resolved: the
+    TPU branch runs the Mosaic kernel (interpret=False) and no launch
+    runs one mode on every platform, so no TPU program can reach the
+    interpreter."""
+    from repro.kernels.ssm_scan import ops as OS
+    from repro.kernels.tri_3body import ops as O3
+    from repro.kernels.tri_edm import ops as OE
+    from repro.kernels.wkv_scan import ops as OW
+
+    OPS, psched, q = _attn_fixture()
+    _, tbl, _, spec, qd, kc = _decode_fixture()
+    x = np.zeros((32, 4), np.float32)
+    f32 = lambda *shape: np.zeros(shape, np.float32)
+    traced = {
+        "packed_prefill_grad": _jaxpr_of(jax.grad(
+            lambda a, b, c: jnp.sum(OPS.packed_prefill_attention(
+                a, b, c, psched, impl="pallas")), argnums=(0, 1, 2)),
+            q, q, q),
+        "tri_attn_grad": _jaxpr_of(jax.grad(
+            lambda a: jnp.sum(OPS.triangular_attention(
+                a, a, a, impl="pallas", block_q=16, block_k=16))),
+            f32(1, 2, 64, 8)),
+        "packed_decode": _jaxpr_of(
+            lambda a, b, c, t: OPS.packed_decode_attention(a, b, c, t, spec),
+            qd, kc, kc, tbl),
+        "tri_edm": _jaxpr_of(lambda v: OE.edm(v, block=8, impl="pallas"), x),
+        "tri_3body": _jaxpr_of(
+            lambda v: O3.three_body(v, block=8, impl="pallas", strict=True),
+            x),
+        "ssm_scan": _jaxpr_of(
+            lambda a, b, c, d: OS.selective_scan(a, b, c, d, d, block_d=8,
+                                                 block_l=8),
+            f32(1, 16, 8), f32(1, 16, 8), f32(8, 4), f32(1, 16, 4)),
+        "wkv_scan": _jaxpr_of(
+            lambda a, u: OW.wkv(a, a, a, a, u, block_l=8),
+            f32(1, 16, 2, 4), f32(2, 4)),
+    }
+    out = []
+    for name, jx in traced.items():
+        n_pc = count_primitive(jx, "pallas_call")
+        bad = interpreted_on_tpu(jx)
+        out.append(_res(
+            f"jaxpr.compiled_on_tpu.{name}", n_pc >= 1 and bad == 0,
+            f"{n_pc} launch(es) on the TPU branch, {bad} that a TPU would "
+            f"interpret (expect >= 1 and 0)"))
+    return out
+
+
 def lint_hlo_scan_invariant() -> List[CheckResult]:
     """Compiled scan-path attention: the while loop's known trip count
     must equal the schedule's step count (reuses roofline/hlo_parse)."""
@@ -428,7 +530,7 @@ def run() -> List[CheckResult]:
     out = []
     for rule_fn in (lint_packed_prefill, lint_triangular_attention,
                     lint_packed_decode, lint_fused_step, lint_tri_kernels,
-                    lint_hlo_scan_invariant):
+                    lint_compiled_on_tpu, lint_hlo_scan_invariant):
         try:
             out.extend(rule_fn())
         except Exception as e:  # a trace crash IS a lint failure

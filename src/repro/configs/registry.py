@@ -4,6 +4,7 @@ ShapeDtypeStruct input specs for the dry-run (no device allocation).
 
 from __future__ import annotations
 
+import dataclasses
 import importlib
 from typing import Dict, Optional, Tuple
 
@@ -139,3 +140,13 @@ def input_specs(arch: str, shape_name: str) -> dict:
 
 def smoke_config(arch: str, **kw) -> ModelConfig:
     return reduced(get_config(arch), **kw)
+
+
+def launch_config(arch: str, full: bool, layers: int = 0) -> ModelConfig:
+    """The config an entry point runs: reduced widths, or the published
+    ones cut to ``layers`` (0 = all) — depth is the only cut at full
+    width."""
+    if not full:
+        return smoke_config(arch)
+    cfg = get_config(arch)
+    return dataclasses.replace(cfg, n_layers=layers) if layers else cfg

@@ -291,14 +291,19 @@ def _tet_row_traced(lam: Array) -> Array:
     """Largest i with tet(i) <= lam, traced (the 3D analogue of the sqrt
     row-finder).
 
-    float32 cbrt(6 lam) gives a candidate within [-1, +2] of the true plane
-    over the whole int32 envelope; TET_PROBES_UP/TET_PROBES_DOWN branch-free
-    corrections make it exact with margin, mirroring ``_isqrt_traced``.
+    A float32 cube root of 6 lam gives a candidate within [-1, +2] of the
+    true plane over the whole int32 envelope; TET_PROBES_UP/TET_PROBES_DOWN
+    branch-free corrections make it exact with margin, mirroring
+    ``_isqrt_traced``.
     Probe arguments are clamped to TET_TRACED_MAX_I so the repair itself
     cannot overflow.
     """
     probe = lambda x: tet(jnp.minimum(x, TET_TRACED_MAX_I))
-    c = jnp.floor(jnp.cbrt(6.0 * lam.astype(jnp.float32))).astype(lam.dtype)
+    # cube root as exp(log(x) / 3): Mosaic lowers exp/log on the TPU
+    # scalar core but has no cbrt or pow. The float error this adds is
+    # certified by repro.analysis.envelope; max(., 1) keeps log off 0.
+    x = jnp.maximum(6.0 * lam.astype(jnp.float32), 1.0)
+    c = jnp.floor(jnp.exp(jnp.log(x) / 3.0)).astype(lam.dtype)
     for _ in range(TET_PROBES_UP):
         c = jnp.where(probe(c + 1) <= lam, c + 1, c)
     for _ in range(TET_PROBES_DOWN):

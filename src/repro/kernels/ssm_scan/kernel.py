@@ -65,7 +65,7 @@ def _ssm_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref, y_ref, hout_ref,
 
 
 def selective_scan(x, dt, A, Bt, Ct, h0=None, *, block_d: int = 256,
-                   block_l: int = 128, interpret: bool = True):
+                   block_l: int = 128):
     """x, dt: (B, L, D); A: (D, N); Bt, Ct: (B, L, N); h0: (B, D, N).
 
     Returns (y (B, L, D) in x.dtype, h_L (B, D, N) f32).
@@ -112,6 +112,5 @@ def selective_scan(x, dt, A, Bt, Ct, h0=None, *, block_d: int = 256,
             jax.ShapeDtypeStruct((b, d, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32)],
-        interpret=interpret,
     )(x, dt, A, Bt, Ct, h0)
     return y, h_out

@@ -1,6 +1,6 @@
 """Public fused-selective-scan op.
 
-impl='pallas' — the TPU kernel (interpret=True on CPU) — serving/forward.
+impl='pallas' — the TPU kernel (interpreted off the TPU) — serving/forward.
 impl='ref'    — the sequential jnp oracle (tests).
 The training path keeps the chunked associative-scan in models/mamba.py
 (measured LOWER traffic than a sequential XLA scan — EXPERIMENTS §Perf);
@@ -15,11 +15,10 @@ from repro.kernels.ssm_scan import ref as R
 
 
 def selective_scan(x, dt, A, Bt, Ct, h0=None, *, impl: str = "pallas",
-                   block_d: int = 256, block_l: int = 128,
-                   interpret: bool = True):
+                   block_d: int = 256, block_l: int = 128):
     if impl == "pallas":
         return K.selective_scan(x, dt, A, Bt, Ct, h0, block_d=block_d,
-                                block_l=block_l, interpret=interpret)
+                                block_l=block_l)
     if impl == "ref":
         return R.selective_scan_ref(x, dt, A, Bt, Ct, h0)
     raise ValueError(f"unknown impl {impl!r}")

@@ -67,7 +67,7 @@ def _triplet_tile(xi, xj, xk, masks=None):
         b = jnp.where(m_bc, b, 0.0)
     ab = jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    return jnp.sum(ab * c)
+    return jnp.sum(ab * c, keepdims=True)  # (1, 1): a vector Mosaic stores
 
 
 def _tet_kernel(x_i_ref, x_j_ref, x_k_ref, out_ref, *, block: int,
@@ -75,13 +75,16 @@ def _tet_kernel(x_i_ref, x_j_ref, x_k_ref, out_ref, *, block: int,
     lam = pl.program_id(0)
     i, j, k = M.tet_map(lam)
     masks = _strict_masks(i, j, k, block) if strict else None
-    out_ref[0, 0] = _triplet_tile(x_i_ref[...], x_j_ref[...], x_k_ref[...],
-                                  masks)
+    out_ref[0] = _triplet_tile(x_i_ref[...], x_j_ref[...], x_k_ref[...],
+                               masks)
 
 
-def three_body_tet(x, block: int, *, strict: bool = False,
-                   interpret: bool = True):
-    """x: (N, d) -> packed (T3, 1) unique-tile-triple reductions."""
+def three_body_tet(x, block: int, *, strict: bool = False):
+    """x: (N, d) -> packed (T3, 1) unique-tile-triple reductions.
+
+    Each step writes a (1, 1) tile of a (T3, 1, 1) output: Mosaic refuses
+    a (1, 1) block of a (T3, 1) array (the second-minor dim must be 8-row
+    tiled or whole)."""
     n_rows, d = x.shape
     assert n_rows % block == 0
     n = n_rows // block
@@ -103,10 +106,9 @@ def three_body_tet(x, block: int, *, strict: bool = False,
             pl.BlockSpec((block, d), lambda lam: (M.tet_map(lam)[1], 0)),
             pl.BlockSpec((block, d), lambda lam: (M.tet_map(lam)[2], 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda lam: (lam, 0)),
-        out_shape=jax.ShapeDtypeStruct((t3, 1), jnp.float32),
-        interpret=interpret,
-    )(x, x, x)
+        out_specs=pl.BlockSpec((1, 1, 1), lambda lam: (lam, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((t3, 1, 1), jnp.float32),
+    )(x, x, x)[:, 0]
 
 
 def _bb3_kernel(x_i_ref, x_j_ref, x_k_ref, out_ref, *, block: int,
@@ -125,11 +127,10 @@ def _bb3_kernel(x_i_ref, x_j_ref, x_k_ref, out_ref, *, block: int,
 
     @pl.when(jnp.logical_not(inside))
     def _():
-        out_ref[0, 0, 0] = 0.0
+        out_ref[...] = jnp.zeros_like(out_ref)
 
 
-def three_body_bb3(x, block: int, *, strict: bool = False,
-                   interpret: bool = True):
+def three_body_bb3(x, block: int, *, strict: bool = False):
     """BB-3D baseline: (n, n, n) output; tiles outside the simplex are
     launched and immediately guarded out — the O(n^3) waste the tet map
     eliminates."""
@@ -148,10 +149,10 @@ def three_body_bb3(x, block: int, *, strict: bool = False,
             pl.BlockSpec((block, d), lambda i, j, k: (j, 0)),
             pl.BlockSpec((block, d), lambda i, j, k: (k, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1), lambda i, j, k: (i, j, k)),
-        out_shape=jax.ShapeDtypeStruct((n, n, n), jnp.float32),
-        interpret=interpret,
-    )(x, x, x)
+        out_specs=pl.BlockSpec((1, 1, 1, 1, 1),
+                               lambda i, j, k: (i, j, k, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, n, n, 1, 1), jnp.float32),
+    )(x, x, x)[..., 0, 0]
 
 
 def _dummy_kernel(out_ref):
@@ -160,7 +161,7 @@ def _dummy_kernel(out_ref):
     out_ref[...] = jnp.full_like(out_ref, (i + j + k).astype(jnp.float32))
 
 
-def dummy_tet(n: int, *, interpret: bool = True):
+def dummy_tet(n: int):
     """3D dummy kernel: map lambda -> (i, j, k), write i+j+k. Pure mapping
     cost; one f32 per block."""
     t3 = M.tet(n)
@@ -170,7 +171,6 @@ def dummy_tet(n: int, *, interpret: bool = True):
                             impl="pallas", kind="tet", steps=t3,
                             block_shape=(1, 1), bb_bound=n * n * n),
         grid=(t3,),
-        out_specs=pl.BlockSpec((1, 1), lambda lam: (lam, 0)),
-        out_shape=jax.ShapeDtypeStruct((t3, 1), jnp.float32),
-        interpret=interpret,
-    )()
+        out_specs=pl.BlockSpec((1, 1, 1), lambda lam: (lam, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((t3, 1, 1), jnp.float32),
+    )()[:, 0]

@@ -1,6 +1,6 @@
 """Public 3-body op: packed tetrahedral triplet-interaction reduction.
 
-impl='pallas'   — tet-grid Pallas kernel (interpret on CPU).
+impl='pallas'   — tet-grid Pallas kernel (interpreted off the TPU).
 impl='scan'     — pure-XLA scan over the tet enumeration (fast CPU path).
 impl='bb3_scan' — bounding-box baseline as a scan: n^3 steps, simplex
                   guard; wasted steps emit zeros (for benchmarks).
@@ -89,7 +89,7 @@ def _three_body_scan_bb3(x, block: int, strict: bool = False):
 
 
 def three_body(x, block: int = 128, *, impl: str = "pallas",
-               strict: bool = False, interpret: bool = True):
+               strict: bool = False):
     """x: (N, d) points -> per-tile-triple reductions.
 
     Packed impls return (T3, 1); 'bb3' returns (n, n, n) with the simplex
@@ -99,20 +99,20 @@ def three_body(x, block: int = 128, *, impl: str = "pallas",
     assert x.shape[0] % block == 0, (
         f"n_rows={x.shape[0]} must be a multiple of block={block}")
     if impl == "pallas":
-        return K.three_body_tet(x, block, strict=strict, interpret=interpret)
+        return K.three_body_tet(x, block, strict=strict)
     if impl == "scan":
         return _three_body_scan(x, block, strict)
     if impl == "bb3_scan":
         return _three_body_scan_bb3(x, block, strict)
     if impl == "bb3":
-        return K.three_body_bb3(x, block, strict=strict, interpret=interpret)
+        return K.three_body_bb3(x, block, strict=strict)
     if impl == "ref":
         return R.three_body_packed_ref(x, block, strict=strict)
     raise ValueError(f"unknown impl {impl!r}")
 
 
 def three_body_total(x, block: int = 128, *, impl: str = "pallas",
-                     strict: bool = False, interpret: bool = True):
+                     strict: bool = False):
     """Total triplet interaction, from the packed unique-tile launch.
 
     strict=False: multiset-permutation-weighted total over ALL ordered
@@ -127,7 +127,7 @@ def three_body_total(x, block: int = 128, *, impl: str = "pallas",
     totals are comparable to the tet launch. The host-side coords table is
     enumerated once and shared with the multiplicity weights."""
     n = x.shape[0] // block
-    out = three_body(x, block, impl=impl, strict=strict, interpret=interpret)
+    out = three_body(x, block, impl=impl, strict=strict)
     coords = R.tet_coords(n)
     if impl == "bb3":
         packed = out[coords[:, 0], coords[:, 1], coords[:, 2]][:, None]

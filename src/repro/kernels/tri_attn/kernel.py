@@ -21,9 +21,14 @@ single-token variant — one mixed-position decode round per launch, the
 scalar-prefetch SMEM over a bucketed static capacity.
 
 All kernels accumulate in f32 VMEM scratch and are validated in interpret
-mode against ref.py (tests/test_kernels_tri_attn.py). TPU notes: block_q and
-block_k should be multiples of 128 (MXU); head_dim 64/128/192 all lower (192
-pads lanes).
+mode against ref.py (tests/test_kernels_tri_attn.py);
+tests/test_chip_compile.py compiles them for a described v5e. TPU notes:
+block_q and block_k should be multiples of 128 (MXU). Mosaic needs the
+last two dims of every block to be multiples of (8, 128) or the array's
+own, so per-row softmax stats travel as (B, H, S, 1), decode queries as
+(B, H, 1, D), and the decode KV cache is read through a free
+(B, S_cache, Hkv * D) view whose blocks are one head wide: decode and the
+fused step need head_dim % 128 == 0 on the chip.
 """
 
 from __future__ import annotations
@@ -287,11 +292,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
     def _emit():
         l = l_s[...]
         o_ref[0, 0] = (acc_s[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_s[...] + jnp.log(l))[:, 0].astype(lse_ref.dtype)
+        lse_ref[0, 0] = m_s[...] + jnp.log(l)
 
 
-def fwd(q, k, v, sched: TriSched, *, sm_scale=None, interpret=True):
-    """q: (B, H, S, D); k, v: (B, Hkv, S, D). Returns (out, lse)."""
+def fwd(q, k, v, sched: TriSched, *, sm_scale=None):
+    """q: (B, H, S, D); k, v: (B, Hkv, S, D). Returns (out, lse) with lse
+    (B, H, S, 1): the trailing unit dim keeps each row block a (bq, 1)
+    column, which Mosaic tiles (a (1, 1, bq) block is refused)."""
     b, h, s_len, d = q.shape
     hkv = k.shape[1]
     g = h // hkv
@@ -315,18 +322,17 @@ def fwd(q, k, v, sched: TriSched, *, sm_scale=None, interpret=True):
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, lam: (b_, h_, rm_i(lam), 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h_, lam: (b_, h_, rm_i(lam))),
+            pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, lam: (b_, h_, rm_i(lam), 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((b, h, s_len), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, s_len, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
     )(q, k, v)
     return out, lse
 
@@ -378,11 +384,10 @@ def _packed_fwd_kernel(tbl_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _emit():
         l = l_s[...]
         o_ref[0, 0] = (acc_s[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_s[...] + jnp.log(l))[:, 0].astype(lse_ref.dtype)
+        lse_ref[0, 0] = m_s[...] + jnp.log(l)
 
 
-def packed_fwd(q, k, v, psched: PackedTriSched, *, sm_scale=None,
-               interpret=True):
+def packed_fwd(q, k, v, psched: PackedTriSched, *, sm_scale=None):
     """Ragged batched prefill in ONE launch.
 
     q: (B, H, S_total, D); k, v: (B, Hkv, S_total, D) — all requests'
@@ -413,7 +418,7 @@ def packed_fwd(q, k, v, psched: PackedTriSched, *, sm_scale=None,
 
     def lse_spec(b_, h_, lam, tbl_):
         _, _, _, q_row, _ = _packed_decode(lam, tbl_, n_req)
-        return (b_, h_, q_row)
+        return (b_, h_, q_row, 0)
 
     kernel = functools.partial(_packed_fwd_kernel, psched=psched, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -426,7 +431,7 @@ def packed_fwd(q, k, v, psched: PackedTriSched, *, sm_scale=None,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, blk, d), q_spec),
-            pl.BlockSpec((1, 1, blk), lse_spec),
+            pl.BlockSpec((1, 1, blk, 1), lse_spec),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk, 1), jnp.float32),
@@ -442,9 +447,8 @@ def packed_fwd(q, k, v, psched: PackedTriSched, *, sm_scale=None,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((b, h, s_len), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, s_len, 1), jnp.float32),
         ],
-        interpret=interpret,
     )(tbl, q, k, v)
     return out, lse
 
@@ -488,8 +492,8 @@ def _packed_dq_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0].astype(jnp.float32)[:, None]
-    delta = delta_ref[0, 0].astype(jnp.float32)[:, None]
+    lse = lse_ref[0, 0]
+    delta = delta_ref[0, 0]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -524,8 +528,8 @@ def _packed_dkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0].astype(jnp.float32)[:, None]
-    delta = delta_ref[0, 0].astype(jnp.float32)[:, None]
+    lse = lse_ref[0, 0]
+    delta = delta_ref[0, 0]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -547,7 +551,7 @@ def _packed_dkv_kernel(tbl_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 
 def packed_bwd(q, k, v, out, lse, do, psched: PackedTriSched, *,
-               sm_scale=None, interpret=True):
+               sm_scale=None):
     """Packed ragged backward: (dq, dk, dv) for a whole mixed-length batch
     in ONE launch per direction (dq row-major, dk/dv column-major — the
     same two 1-D grids the per-domain ``bwd`` uses, lifted to the packed
@@ -564,7 +568,8 @@ def packed_bwd(q, k, v, out, lse, do, psched: PackedTriSched, *,
     blk = psched.blk
     n_req = len(psched.members)
     tbl = np.ascontiguousarray(psched.table())
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1, keepdims=True)
 
     def rm_q(b_, h_, lam, tbl_):
         _, _, _, q_row, _ = _packed_decode(lam, tbl_, n_req)
@@ -576,7 +581,7 @@ def packed_bwd(q, k, v, out, lse, do, psched: PackedTriSched, *,
 
     def rm_row(b_, h_, lam, tbl_):
         _, _, _, q_row, _ = _packed_decode(lam, tbl_, n_req)
-        return (b_, h_, q_row)
+        return (b_, h_, q_row, 0)
 
     dq = OBS.instrumented_pallas_call(
         functools.partial(_packed_dq_kernel, n_requests=n_req, blk=blk,
@@ -592,14 +597,13 @@ def packed_bwd(q, k, v, out, lse, do, psched: PackedTriSched, *,
                 pl.BlockSpec((1, 1, blk, d), rm_kv),
                 pl.BlockSpec((1, 1, blk, d), rm_kv),
                 pl.BlockSpec((1, 1, blk, d), rm_q),
-                pl.BlockSpec((1, 1, blk), rm_row),
-                pl.BlockSpec((1, 1, blk), rm_row),
+                pl.BlockSpec((1, 1, blk, 1), rm_row),
+                pl.BlockSpec((1, 1, blk, 1), rm_row),
             ],
             out_specs=pl.BlockSpec((1, 1, blk, d), rm_q),
             scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
     )(tbl, q, k, v, do, lse, delta)
 
     def cm_q(b_, h_, lam, tbl_):
@@ -612,7 +616,7 @@ def packed_bwd(q, k, v, out, lse, do, psched: PackedTriSched, *,
 
     def cm_row(b_, h_, lam, tbl_):
         _, _, _, q_row, _ = _packed_decode_cm(lam, tbl_, n_req)
-        return (b_, h_, q_row)
+        return (b_, h_, q_row, 0)
 
     def cm_out(b_, h_, lam, tbl_):
         _, _, _, _, k_row = _packed_decode_cm(lam, tbl_, n_req)
@@ -632,8 +636,8 @@ def packed_bwd(q, k, v, out, lse, do, psched: PackedTriSched, *,
                 pl.BlockSpec((1, 1, blk, d), cm_kv),
                 pl.BlockSpec((1, 1, blk, d), cm_kv),
                 pl.BlockSpec((1, 1, blk, d), cm_q),
-                pl.BlockSpec((1, 1, blk), cm_row),
-                pl.BlockSpec((1, 1, blk), cm_row),
+                pl.BlockSpec((1, 1, blk, 1), cm_row),
+                pl.BlockSpec((1, 1, blk, 1), cm_row),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, blk, d), cm_out),
@@ -648,7 +652,6 @@ def packed_bwd(q, k, v, out, lse, do, psched: PackedTriSched, *,
             jax.ShapeDtypeStruct((b, h, s_len, d), k.dtype),
             jax.ShapeDtypeStruct((b, h, s_len, d), v.dtype),
         ],
-        interpret=interpret,
     )(tbl, q, k, v, do, lse, delta)
 
     if g > 1:  # sum per-q-head partials into kv heads
@@ -716,9 +719,9 @@ def _packed_decode_kernel(tbl_ref, q_ref, k_ref, v_ref, o_ref,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    q = q_ref[0].astype(jnp.float32)           # (1, d)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (blk, d)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)  # (1, d)
+    k = k_ref[0].astype(jnp.float32)     # (blk, d): one kv head's columns
+    v = v_ref[0].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     kpos = (kv_first // blk + j) * blk + jax.lax.broadcasted_iota(
@@ -736,17 +739,19 @@ def _packed_decode_kernel(tbl_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j == kv_tiles - 1)
     def _emit():
-        o_ref[0] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
 
 
 def packed_decode_fwd(q, k, v, tbl, *, capacity: int, blk: int,
-                      sm_scale=None, interpret=True):
+                      sm_scale=None):
     """One packed launch for a whole mixed-position decode round.
 
     q: (B, H, D) — each slot's single rotated query; k, v: (B, S_cache,
     Hkv, D) — the NATIVE decode-cache layout (no transposes on the hot
-    path), new token already written. tbl: (5, R) runtime member table
-    (ops.make_decode_table). Grid is (H, capacity): sum_r kv_tiles_r live
+    path; the kernel reads it through the free (B, S_cache, Hkv * D)
+    view, one head's D columns per block), new token already written.
+    tbl: (5, R) runtime member table (ops.make_decode_table). Grid is
+    (H, capacity): sum_r kv_tiles_r live
     steps + masked pad steps, vs the lockstep einsum's B * S_cache work.
     Band-limited members (kv_first > 0) read only cache tiles
     [kv_first // blk, ceil(kv_len / blk)). Returns (B + 1, H, D): row B is
@@ -763,19 +768,19 @@ def packed_decode_fwd(q, k, v, tbl, *, capacity: int, blk: int,
 
     def q_spec(h_, lam, tbl_):
         _, slot, _, _, _, _ = _decode_member(lam, tbl_, n_members)
-        return (jnp.minimum(slot, b - 1), h_, 0)
+        return (jnp.minimum(slot, b - 1), h_, 0, 0)
 
     def kv_spec(h_, lam, tbl_):
         _, slot, j, _, _, kv_first = _decode_member(lam, tbl_, n_members)
         return (jnp.minimum(slot, b - 1),
                 jnp.minimum(kv_first // blk + j, cache_tiles - 1),
-                h_ // g, 0)
+                h_ // g)
 
     def o_spec(h_, lam, tbl_):
         # pad member's slot == b: the extra garbage row, so pad steps can
         # never flush stale VMEM over a live slot's emitted block.
         _, slot, _, _, _, _ = _decode_member(lam, tbl_, n_members)
-        return (slot, h_, 0)
+        return (slot, h_, 0, 0)
 
     kernel = functools.partial(_packed_decode_kernel, n_members=n_members,
                                blk=blk, scale=scale)
@@ -783,11 +788,11 @@ def packed_decode_fwd(q, k, v, tbl, *, capacity: int, blk: int,
         num_scalar_prefetch=1,
         grid=(h, capacity),
         in_specs=[
-            pl.BlockSpec((1, 1, d), q_spec),
-            pl.BlockSpec((1, blk, 1, d), kv_spec),
-            pl.BlockSpec((1, blk, 1, d), kv_spec),
+            pl.BlockSpec((1, 1, 1, d), q_spec),
+            pl.BlockSpec((1, blk, d), kv_spec),
+            pl.BlockSpec((1, blk, d), kv_spec),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), o_spec),
+        out_specs=pl.BlockSpec((1, 1, 1, d), o_spec),
         scratch_shapes=[
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
@@ -802,10 +807,10 @@ def packed_decode_fwd(q, k, v, tbl, *, capacity: int, blk: int,
                             bb_bound=b * cache_tiles, cells=h,
                             extra=(("capacity", capacity),)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b + 1, h, d), q.dtype),
-        interpret=interpret,
-    )(tbl, q, k, v)
-    return out
+        out_shape=jax.ShapeDtypeStruct((b + 1, h, 1, d), q.dtype),
+    )(tbl, q[:, :, None], k.reshape(b, s_cache, hkv * d),
+      v.reshape(b, s_cache, hkv * d))
+    return out[:, :, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -874,19 +879,22 @@ def _fused_step_kernel(tbl_ref, qp_ref, kp_ref, vp_ref, qd_ref, kc_ref,
     # Decode rows broadcast their single query over the block: every row
     # computes the same online softmax, and the emit takes row 0.
     qp = qp_ref[0, 0].astype(jnp.float32)           # (blk, d)
-    qd = qd_ref[0].astype(jnp.float32)              # (1, d)
+    qd = qd_ref[0, 0].astype(jnp.float32)           # (1, d)
     q = jnp.where(is_p, qp, jnp.broadcast_to(qd, qp.shape))
     k = jnp.where(is_p, kp_ref[0, 0].astype(jnp.float32),
-                  kc_ref[0, :, 0, :].astype(jnp.float32))
+                  kc_ref[0].astype(jnp.float32))
     v = jnp.where(is_p, vp_ref[0, 0].astype(jnp.float32),
-                  vc_ref[0, :, 0, :].astype(jnp.float32))
+                  vc_ref[0].astype(jnp.float32))
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     pmask = _packed_token_mask(i_p, j_p, blk, tbl_ref[6, r], tbl_ref[7, r])
     kpos = (kv_first // blk + jnp.where(is_p, 0, local)) * blk \
         + jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
     dmask = (kpos >= kv_first) & (kpos < kv_len)
-    s = jnp.where(jnp.where(is_p, pmask, dmask), s, MASK_VALUE)
+    # select between the two masked scores, not between the masks: Mosaic
+    # cannot lower a select over boolean vectors
+    s = jnp.where(is_p, jnp.where(pmask, s, MASK_VALUE),
+                  jnp.where(dmask, s, MASK_VALUE))
 
     m_prev = m_s[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -903,19 +911,20 @@ def _fused_step_kernel(tbl_ref, qp_ref, kp_ref, vp_ref, qd_ref, kc_ref,
 
     @pl.when(jnp.logical_not(is_p) & (j_eff == last))
     def _emit_dec():
-        od_ref[0] = (acc_s[0:1, :] / l_s[0:1, :]).astype(od_ref.dtype)
+        od_ref[0, 0] = (acc_s[0:1, :] / l_s[0:1, :]).astype(od_ref.dtype)
 
 
 def fused_step_fwd(q_pack, k_pack, v_pack, q_dec, k_cache, v_cache, tbl, *,
                    capacity: int, blk: int, n_pack_tiles: int,
-                   sm_scale=None, interpret=True):
+                   sm_scale=None):
     """One fused launch for a whole continuous-batching engine step.
 
     q_pack: (1, H, S_pack, D) with k_pack/v_pack (1, Hkv, S_pack, D) — the
     newly admitted prompts concatenated along S (the packed-prefill
     layout); q_dec: (B, H, D) with k_cache/v_cache (B, S_cache, Hkv, D) —
-    the live slots' rotated queries against the native decode cache, new
-    token already written. tbl: the (8, R) fused member table
+    the live slots' rotated queries against the native decode cache (read
+    as in packed_decode_fwd), new token already written. tbl: the (8, R)
+    fused member table
     (ops.make_fused_table). Grid is (H, capacity): prefill blocks + live
     decode tiles + masked pad steps — ONE pallas_call where the split
     engine paid an admit launch and a decode launch. Returns
@@ -947,7 +956,7 @@ def fused_step_fwd(q_pack, k_pack, v_pack, q_dec, k_cache, v_cache, tbl, *,
     def qd_spec(h_, lam, tbl_):
         r, is_p, _, _, _ = _fused_member(lam, tbl_, n_members)
         slot = jnp.where(is_p, 0, tbl_[5, r])
-        return (jnp.minimum(slot, b - 1), h_, 0)
+        return (jnp.minimum(slot, b - 1), h_, 0, 0)
 
     def kc_spec(h_, lam, tbl_):
         r, is_p, local, _, _ = _fused_member(lam, tbl_, n_members)
@@ -956,7 +965,7 @@ def fused_step_fwd(q_pack, k_pack, v_pack, q_dec, k_cache, v_cache, tbl, *,
         j_d = jnp.where(is_p, 0, local)
         return (jnp.minimum(slot, b - 1),
                 jnp.minimum(kv_first // blk + j_d, cache_tiles - 1),
-                h_ // g, 0)
+                h_ // g)
 
     def op_spec(h_, lam, tbl_):
         # decode/pad steps park on the extra garbage tile row n_pack_tiles
@@ -967,7 +976,7 @@ def fused_step_fwd(q_pack, k_pack, v_pack, q_dec, k_cache, v_cache, tbl, *,
         # prefill steps (and the pad member, whose slot is n_slots) park on
         # the garbage row b of the (B + 1)-row decode output
         r, is_p, _, _, _ = _fused_member(lam, tbl_, n_members)
-        return (jnp.where(is_p, b, jnp.minimum(tbl_[5, r], b)), h_, 0)
+        return (jnp.where(is_p, b, jnp.minimum(tbl_[5, r], b)), h_, 0, 0)
 
     kernel = functools.partial(_fused_step_kernel, n_members=n_members,
                                blk=blk, scale=scale)
@@ -978,13 +987,13 @@ def fused_step_fwd(q_pack, k_pack, v_pack, q_dec, k_cache, v_cache, tbl, *,
             pl.BlockSpec((1, 1, blk, d), qp_spec),
             pl.BlockSpec((1, 1, blk, d), kp_spec),
             pl.BlockSpec((1, 1, blk, d), kp_spec),
-            pl.BlockSpec((1, 1, d), qd_spec),
-            pl.BlockSpec((1, blk, 1, d), kc_spec),
-            pl.BlockSpec((1, blk, 1, d), kc_spec),
+            pl.BlockSpec((1, 1, 1, d), qd_spec),
+            pl.BlockSpec((1, blk, d), kc_spec),
+            pl.BlockSpec((1, blk, d), kc_spec),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, blk, d), op_spec),
-            pl.BlockSpec((1, 1, d), od_spec),
+            pl.BlockSpec((1, 1, 1, d), od_spec),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk, 1), jnp.float32),
@@ -1003,11 +1012,12 @@ def fused_step_fwd(q_pack, k_pack, v_pack, q_dec, k_cache, v_cache, tbl, *,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((1, h, s_pack + blk, d), q_pack.dtype),
-            jax.ShapeDtypeStruct((b + 1, h, d), q_dec.dtype),
+            jax.ShapeDtypeStruct((b + 1, h, 1, d), q_dec.dtype),
         ],
-        interpret=interpret,
-    )(tbl, q_pack, k_pack, v_pack, q_dec, k_cache, v_cache)
-    return o_pack, o_dec
+    )(tbl, q_pack, k_pack, v_pack, q_dec[:, :, None],
+      k_cache.reshape(b, s_cache, hkv * d),
+      v_cache.reshape(b, s_cache, hkv * d))
+    return o_pack, o_dec[:, :, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -1028,8 +1038,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0].astype(jnp.float32)[:, None]
-    delta = delta_ref[0, 0].astype(jnp.float32)[:, None]
+    lse = lse_ref[0, 0]
+    delta = delta_ref[0, 0]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -1066,8 +1076,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0].astype(jnp.float32)[:, None]
-    delta = delta_ref[0, 0].astype(jnp.float32)[:, None]
+    lse = lse_ref[0, 0]
+    delta = delta_ref[0, 0]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -1087,15 +1097,15 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0, 0] = dv_s[...].astype(dv_ref.dtype)
 
 
-def bwd(q, k, v, out, lse, do, sched: TriSched, *, sm_scale=None,
-        interpret=True):
+def bwd(q, k, v, out, lse, do, sched: TriSched, *, sm_scale=None):
     """Returns (dq, dk, dv) with dk/dv shaped like k/v (group-summed)."""
     b, h, s_len, d = q.shape
     hkv = k.shape[1]
     g = h // hkv
     scale = sm_scale if sm_scale is not None else 1.0 / (d ** 0.5)
     bq, bk = sched.bq, sched.bk
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1, keepdims=True)
 
     rm_i = lambda lam: sched.rm_map(lam)[0]
     rm_j = lambda lam: sched.rm_map(lam)[1]
@@ -1110,14 +1120,13 @@ def bwd(q, k, v, out, lse, do, sched: TriSched, *, sm_scale=None,
             pl.BlockSpec((1, 1, bk, d), lambda b_, h_, lam: (b_, h_ // g, rm_j(lam), 0)),
             pl.BlockSpec((1, 1, bk, d), lambda b_, h_, lam: (b_, h_ // g, rm_j(lam), 0)),
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, lam: (b_, h_, rm_i(lam), 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h_, lam: (b_, h_, rm_i(lam))),
-            pl.BlockSpec((1, 1, bq), lambda b_, h_, lam: (b_, h_, rm_i(lam))),
+            pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, lam: (b_, h_, rm_i(lam), 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, lam: (b_, h_, rm_i(lam), 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d),
                                lambda b_, h_, lam: (b_, h_, rm_i(lam), 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
     )(q, k, v, do, lse, delta)
 
     cm_i = lambda lam: sched.cm_map(lam)[0]
@@ -1134,8 +1143,8 @@ def bwd(q, k, v, out, lse, do, sched: TriSched, *, sm_scale=None,
             pl.BlockSpec((1, 1, bk, d), lambda b_, h_, lam: (b_, h_ // g, cm_j(lam), 0)),
             pl.BlockSpec((1, 1, bk, d), lambda b_, h_, lam: (b_, h_ // g, cm_j(lam), 0)),
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, lam: (b_, h_, cm_i(lam), 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h_, lam: (b_, h_, cm_i(lam))),
-            pl.BlockSpec((1, 1, bq), lambda b_, h_, lam: (b_, h_, cm_i(lam))),
+            pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, lam: (b_, h_, cm_i(lam), 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, lam: (b_, h_, cm_i(lam), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda b_, h_, lam: (b_, h_, cm_j(lam), 0)),
@@ -1145,7 +1154,6 @@ def bwd(q, k, v, out, lse, do, sched: TriSched, *, sm_scale=None,
             jax.ShapeDtypeStruct((b, h, s_len, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, s_len, d), q.dtype),
         ],
-        interpret=interpret,
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
@@ -1199,10 +1207,10 @@ def _bb_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
     def _emit():
         l = l_s[...]
         o_ref[0, 0] = (acc_s[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_s[...] + jnp.log(l))[:, 0].astype(lse_ref.dtype)
+        lse_ref[0, 0] = m_s[...] + jnp.log(l)
 
 
-def fwd_bb(q, k, v, sched: TriSched, *, sm_scale=None, interpret=True):
+def fwd_bb(q, k, v, sched: TriSched, *, sm_scale=None):
     """Bounding-box baseline: n x n grid, upper tiles guarded (dead DMA +
     dead grid steps — the cost the paper eliminates)."""
     b, h, s_len, d = q.shape
@@ -1224,17 +1232,16 @@ def fwd_bb(q, k, v, sched: TriSched, *, sm_scale=None, interpret=True):
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h_, i, j: (b_, h_, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, i, j: (b_, h_, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((b, h, s_len), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, s_len, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
     )(q, k, v)
     return out, lse

@@ -3,7 +3,9 @@
 ``triangular_attention`` is the single entry point the models use. It picks
 the schedule kind from the mask parameters, and dispatches between:
 
-  impl='pallas' — the TPU Pallas kernels (kernel.py); interpret=True on CPU.
+  impl='pallas' — the Pallas kernels (kernel.py): compiled on a TPU,
+                  interpreted elsewhere (decided at obs.launch's one
+                  pallas_call site from the lowering platform).
   impl='scan'   — the pure-XLA LTM scan (scan_impl.py); the dry-run / CPU
                   training path. Differentiable via custom VJP.
   impl='ref'    — the O(S^2)-memory oracle (ref.py); tests only.
@@ -38,6 +40,13 @@ from repro.kernels.tri_attn import ref as R
 from repro.kernels.tri_attn import scan_impl as SC
 from repro.kernels.tri_attn.kernel import (DECODE_NO_EMIT, PackedTriSched,
                                            TriSched)
+
+
+def platform_impl() -> str:
+    """The attention impl for the platform this process computes on:
+    the Pallas kernels on a TPU, the XLA scan mirror elsewhere (the CPU
+    test suite, where the Pallas interpreter would be far slower)."""
+    return "pallas" if jax.default_backend() == "tpu" else "scan"
 
 
 def make_sched(s_len: int, *, block_q: int, block_k: int, window=None,
@@ -95,8 +104,7 @@ def make_packed_sched(seq_lens, *, block: int, window=None,
 
 
 @functools.lru_cache(maxsize=None)
-def _packed_pallas_attention(psched: PackedTriSched, scale: float,
-                             interpret: bool):
+def _packed_pallas_attention(psched: PackedTriSched, scale: float):
     """Custom-VJP packed Pallas attention for static (psched, scale):
     jax.grad issues ONE packed_bwd launch per direction (dq row-major,
     dk/dv column-major) over the forward's (7, R) member table — no
@@ -104,27 +112,23 @@ def _packed_pallas_attention(psched: PackedTriSched, scale: float,
 
     @jax.custom_vjp
     def attn(q, k, v):
-        out, _ = K.packed_fwd(q, k, v, psched, sm_scale=scale,
-                              interpret=interpret)
+        out, _ = K.packed_fwd(q, k, v, psched, sm_scale=scale)
         return out
 
     def attn_fwd(q, k, v):
-        out, lse = K.packed_fwd(q, k, v, psched, sm_scale=scale,
-                                interpret=interpret)
+        out, lse = K.packed_fwd(q, k, v, psched, sm_scale=scale)
         return out, (q, k, v, out, lse)
 
     def attn_bwd(res, do):
         q, k, v, out, lse = res
-        return K.packed_bwd(q, k, v, out, lse, do, psched, sm_scale=scale,
-                            interpret=interpret)
+        return K.packed_bwd(q, k, v, out, lse, do, psched, sm_scale=scale)
 
     attn.defvjp(attn_fwd, attn_bwd)
     return attn
 
 
 def packed_prefill_attention(q, k, v, psched: PackedTriSched, *,
-                             sm_scale=None, impl: str = "scan",
-                             interpret: bool = True):
+                             sm_scale=None, impl: str = "scan"):
     """Ragged batched attention over the packed layout (prefill AND train).
 
     q: (B, H, S_total, D); k, v: (B, Hkv, S_total, D) — every batch row
@@ -140,7 +144,7 @@ def packed_prefill_attention(q, k, v, psched: PackedTriSched, *,
         f"{psched.s_total}")
     scale = float(sm_scale if sm_scale is not None else 1.0 / (d ** 0.5))
     if impl == "pallas":
-        return _packed_pallas_attention(psched, scale, interpret)(q, k, v)
+        return _packed_pallas_attention(psched, scale)(q, k, v)
     if impl == "scan":
         return SC.make_packed_scan_attention(psched, scale)(q, k, v)
     if impl == "ref":
@@ -239,8 +243,7 @@ def make_decode_table(kv_lens, slots, *, blk: int, n_members: int,
 
 
 def packed_decode_attention(q, k_cache, v_cache, tbl,
-                            spec: DecodeRoundSpec, *, sm_scale=None,
-                            interpret: bool = True):
+                            spec: DecodeRoundSpec, *, sm_scale=None):
     """Single-token attention for a whole mixed-position decode round.
 
     q: (B, H, D) rotated queries (one new token per slot); k_cache,
@@ -260,7 +263,7 @@ def packed_decode_attention(q, k_cache, v_cache, tbl,
     if spec.impl == "pallas":
         full = K.packed_decode_fwd(q, k_cache, v_cache, tbl,
                                    capacity=spec.capacity, blk=spec.blk,
-                                   sm_scale=scale, interpret=interpret)
+                                   sm_scale=scale)
         covered = _covered_slots(tbl, b)
         return jnp.where(covered[:, None, None], full[:b], 0)
     if spec.impl == "scan":
@@ -268,13 +271,19 @@ def packed_decode_attention(q, k_cache, v_cache, tbl,
                                      capacity=spec.capacity, blk=spec.blk,
                                      n_members=spec.n_members, scale=scale)
     if spec.impl == "ref":
-        kv_len = _slot_kv_lens(tbl, b)
-        kv_first = _slot_kv_firsts(tbl, b)
-        srng = jnp.arange(s_cache)[None, :]
-        valid = (srng >= kv_first[:, None]) & (srng < kv_len[:, None])
-        out = _masked_decode_einsum(q, k_cache, v_cache, valid, scale)
-        return jnp.where(kv_len[:, None, None] > 0, out, 0)
+        return _decode_ref(q, k_cache, v_cache, tbl, scale)
     raise ValueError(f"unknown impl {spec.impl!r}")
+
+
+def _decode_ref(q, k_cache, v_cache, tbl, scale):
+    """Masked-einsum oracle of a decode round over its (5, R) table."""
+    b = q.shape[0]
+    kv_len = _slot_kv_lens(tbl, b)
+    kv_first = _slot_kv_firsts(tbl, b)
+    srng = jnp.arange(k_cache.shape[1])[None, :]
+    valid = (srng >= kv_first[:, None]) & (srng < kv_len[:, None])
+    out = _masked_decode_einsum(q, k_cache, v_cache, valid, scale)
+    return jnp.where(kv_len[:, None, None] > 0, out, 0)
 
 
 def _covered_slots(tbl, b):
@@ -369,7 +378,7 @@ def make_fused_table(psched: PackedTriSched, kv_lens, slots, *, blk: int,
 
 def fused_step_attention(q_pack, k_pack, v_pack, q_dec, k_cache, v_cache,
                          tbl, psched: PackedTriSched, spec: FusedStepSpec,
-                         *, sm_scale=None, interpret: bool = True):
+                         *, sm_scale=None):
     """One attention launch for a whole continuous-batching engine step.
 
     q_pack: (1, H, S_pack, D) packed admitted prompts (k_pack/v_pack
@@ -392,8 +401,7 @@ def fused_step_attention(q_pack, k_pack, v_pack, q_dec, k_cache, v_cache,
         o_pack, o_dec = K.fused_step_fwd(
             q_pack, k_pack, v_pack, q_dec, k_cache, v_cache, tbl,
             capacity=spec.capacity, blk=spec.blk,
-            n_pack_tiles=s_pack // spec.blk, sm_scale=scale,
-            interpret=interpret)
+            n_pack_tiles=s_pack // spec.blk, sm_scale=scale)
         covered = _fused_covered_slots(tbl, b)
         return (o_pack[:, :, :s_pack],
                 jnp.where(covered[:, None, None], o_dec[:b], 0))
@@ -402,6 +410,14 @@ def fused_step_attention(q_pack, k_pack, v_pack, q_dec, k_cache, v_cache,
             q_pack, k_pack, v_pack, q_dec, k_cache, v_cache, tbl,
             capacity=spec.capacity, blk=spec.blk,
             n_members=spec.n_members, scale=scale)
+    if spec.impl == "ref":
+        # the two halves' oracles: per-member prefill and, over the decode
+        # columns re-read as a (5, R) decode table, the masked einsum
+        r_p = len(psched.members)
+        dec_tbl = jnp.stack([tbl[0], tbl[5], tbl[2], tbl[3], tbl[4]])[:, r_p:]
+        return (packed_prefill_attention(q_pack, k_pack, v_pack, psched,
+                                         sm_scale=scale, impl="ref"),
+                _decode_ref(q_dec, k_cache, v_cache, dec_tbl, scale))
     raise ValueError(f"unknown impl {spec.impl!r}")
 
 
@@ -413,20 +429,19 @@ def _fused_covered_slots(tbl, b):
 
 
 @functools.lru_cache(maxsize=None)
-def _pallas_attention(sched: TriSched, scale: float, interpret: bool):
+def _pallas_attention(sched: TriSched, scale: float):
     @jax.custom_vjp
     def attn(q, k, v):
-        out, _ = K.fwd(q, k, v, sched, sm_scale=scale, interpret=interpret)
+        out, _ = K.fwd(q, k, v, sched, sm_scale=scale)
         return out
 
     def attn_fwd(q, k, v):
-        out, lse = K.fwd(q, k, v, sched, sm_scale=scale, interpret=interpret)
+        out, lse = K.fwd(q, k, v, sched, sm_scale=scale)
         return out, (q, k, v, out, lse)
 
     def attn_bwd(res, do):
         q, k, v, out, lse = res
-        return K.bwd(q, k, v, out, lse, do, sched, sm_scale=scale,
-                     interpret=interpret)
+        return K.bwd(q, k, v, out, lse, do, sched, sm_scale=scale)
 
     attn.defvjp(attn_fwd, attn_bwd)
     return attn
@@ -434,8 +449,7 @@ def _pallas_attention(sched: TriSched, scale: float, interpret: bool):
 
 def triangular_attention(q, k, v, *, window=None, prefix: int = 0,
                          sm_scale=None, impl: str = "scan",
-                         block_q: int = 256, block_k: int = 256,
-                         interpret: bool = True):
+                         block_q: int = 256, block_k: int = 256):
     """Causal (optionally windowed / prefix-causal) attention.
 
     q: (B, H, S, D); k, v: (B, Hkv, S, D), H % Hkv == 0. Returns (B, H, S, D).
@@ -448,10 +462,10 @@ def triangular_attention(q, k, v, *, window=None, prefix: int = 0,
     sched = make_sched(s_len, block_q=block_q, block_k=block_k,
                        window=window, prefix=prefix)
     if impl == "pallas":
-        return _pallas_attention(sched, scale, interpret)(q, k, v)
+        return _pallas_attention(sched, scale)(q, k, v)
     if impl == "scan":
         return SC.make_scan_attention(sched, scale)(q, k, v)
     if impl == "bb":
-        out, _ = K.fwd_bb(q, k, v, sched, sm_scale=scale, interpret=interpret)
+        out, _ = K.fwd_bb(q, k, v, sched, sm_scale=scale)
         return out
     raise ValueError(f"unknown impl {impl!r}")

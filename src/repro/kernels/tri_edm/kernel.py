@@ -47,7 +47,7 @@ def _ltm_kernel(x_i_ref, x_j_ref, out_ref, *, squared: bool):
     out_ref[0] = _edm_tile(x_i_ref[...], x_j_ref[...], i, j, squared=squared)
 
 
-def edm_ltm(x, block: int, *, squared: bool = False, interpret: bool = True):
+def edm_ltm(x, block: int, *, squared: bool = False):
     """x: (N, d) -> packed (T, block, block) lower-tri EDM blocks."""
     n_rows, d = x.shape
     assert n_rows % block == 0
@@ -69,7 +69,6 @@ def edm_ltm(x, block: int, *, squared: bool = False, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((1, block, block), lambda lam: (lam, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((t, block, block), jnp.float32),
-        interpret=interpret,
     )(x, x)
 
 
@@ -87,7 +86,7 @@ def _bb_kernel(x_i_ref, x_j_ref, out_ref, *, squared: bool):
         out_ref[...] = jnp.zeros_like(out_ref)
 
 
-def edm_bb(x, block: int, *, squared: bool = False, interpret: bool = True):
+def edm_bb(x, block: int, *, squared: bool = False):
     """BB baseline: full (N, N) output; tiles with j > i are wasted work."""
     n_rows, d = x.shape
     assert n_rows % block == 0
@@ -104,7 +103,6 @@ def edm_bb(x, block: int, *, squared: bool = False, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((block, block), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_rows, n_rows), jnp.float32),
-        interpret=interpret,
     )(x, x)
 
 
@@ -114,7 +112,7 @@ def _dummy_kernel(out_ref):
     out_ref[...] = jnp.full_like(out_ref, (i + j).astype(jnp.float32))
 
 
-def dummy_ltm(n: int, *, interpret: bool = True):
+def dummy_ltm(n: int):
     """Paper's dummy kernel: map lambda -> (i, j), write i+j. Pure mapping
     cost; one f32 per block."""
     t = M.tri(n)
@@ -124,7 +122,6 @@ def dummy_ltm(n: int, *, interpret: bool = True):
                             kind="ltm", steps=t, block_shape=(1, 1),
                             bb_bound=n * n),
         grid=(t,),
-        out_specs=pl.BlockSpec((1, 1), lambda lam: (lam, 0)),
-        out_shape=jax.ShapeDtypeStruct((t, 1), jnp.float32),
-        interpret=interpret,
-    )()
+        out_specs=pl.BlockSpec((1, 1, 1), lambda lam: (lam, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((t, 1, 1), jnp.float32),
+    )()[:, 0]

@@ -1,6 +1,6 @@
 """Public EDM op: packed triangular Euclidean distance matrix.
 
-impl='pallas' — LTM Pallas kernel (interpret on CPU).
+impl='pallas' — LTM Pallas kernel (interpreted off the TPU).
 impl='scan'   — pure-XLA scan over the LTM enumeration (fast CPU path used
                 by the paper-reproduction benchmarks at large N).
 impl='bb'     — bounding-box Pallas baseline (full output).
@@ -84,21 +84,20 @@ def _edm_scan_bb(x, block: int, *, squared: bool = False):
     return blocks
 
 
-def edm(x, block: int = 128, *, squared: bool = False, impl: str = "pallas",
-        interpret: bool = True):
+def edm(x, block: int = 128, *, squared: bool = False, impl: str = "pallas"):
     """x: (N, d) features -> EDM.
 
     Packed impls return (T, block, block); 'bb'/'ref' return full/guarded
     grids ('bb_scan' returns (n*n, block, block) with zeroed dead tiles).
     """
     if impl == "pallas":
-        return K.edm_ltm(x, block, squared=squared, interpret=interpret)
+        return K.edm_ltm(x, block, squared=squared)
     if impl == "scan":
         return _edm_scan(x, block, squared=squared)
     if impl == "bb_scan":
         return _edm_scan_bb(x, block, squared=squared)
     if impl == "bb":
-        return K.edm_bb(x, block, squared=squared, interpret=interpret)
+        return K.edm_bb(x, block, squared=squared)
     if impl == "ref":
         return R.edm_full(x, squared=squared)
     raise ValueError(f"unknown impl {impl!r}")

@@ -58,8 +58,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, out_ref,
         sout_ref[0, 0] = s.astype(sout_ref.dtype)
 
 
-def wkv(r, k, v, lw, u, s0=None, *, block_l: int = 64,
-        interpret: bool = True):
+def wkv(r, k, v, lw, u, s0=None, *, block_l: int = 64):
     """r, k, v, lw: (B, L, H, hd); u: (H, hd); s0: (B, H, hd, hd).
 
     Returns (out (B, L, H, hd) in r.dtype, s_L (B, H, hd, hd) f32)."""
@@ -96,6 +95,5 @@ def wkv(r, k, v, lw, u, s0=None, *, block_l: int = 64,
             jax.ShapeDtypeStruct((b, h, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        interpret=interpret,
     )(r, k, v, lw, u, s0)
     return out, s_out

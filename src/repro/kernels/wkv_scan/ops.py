@@ -1,4 +1,4 @@
-"""Public fused WKV6 op. impl='pallas' (TPU kernel; interpret on CPU) or
+"""Public fused WKV6 op. impl='pallas' (TPU kernel; interpreted off the TPU) or
 'ref' (sequential oracle). The training path keeps the chunked
 exp-argument formulation in models/rwkv6.py (numerically matched — see
 tests); the kernel is the TPU-native replacement the roofline's
@@ -11,10 +11,9 @@ from repro.kernels.wkv_scan import ref as R
 
 
 def wkv(r, k, v, lw, u, s0=None, *, impl: str = "pallas",
-        block_l: int = 64, interpret: bool = True):
+        block_l: int = 64):
     if impl == "pallas":
-        return K.wkv(r, k, v, lw, u, s0, block_l=block_l,
-                     interpret=interpret)
+        return K.wkv(r, k, v, lw, u, s0, block_l=block_l)
     if impl == "ref":
         return R.wkv_ref(r, k, v, lw, u, s0)
     raise ValueError(f"unknown impl {impl!r}")

@@ -226,8 +226,9 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
         analysis = HLO.analyze_compiled(compiled)
         rec["analysis"] = {k: v for k, v in analysis.items()}
         mf = RF.model_flops(cfg, shape)
+        # the dry-run lowers for the v5e pod (launch/mesh.py)
         terms = RF.terms_from_analysis(analysis, n_chips=n_chips,
-                                       model_flops=mf)
+                                       device_kind=RF.V5E, model_flops=mf)
         rec["roofline"] = terms.as_dict()
         rec["ok"] = True
     except Exception as e:  # noqa: BLE001 — record the failure, keep going

@@ -1,11 +1,18 @@
 """Mesh construction. FUNCTIONS only — importing this module must never
-touch jax device state (dryrun.py sets XLA_FLAGS before first jax init)."""
+touch jax device state (dryrun.py sets XLA_FLAGS before first jax init).
+All mesh construction goes through ``make_mesh``."""
 
 from __future__ import annotations
 
 import jax
 
-from repro.launch.compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with every axis in Auto mode: the sharding rules
+    (parallel/sharding.py) leave propagation to XLA's partitioner."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,11 +1,13 @@
 """Training driver.
 
-Runs REAL steps, so on this CPU container it targets the reduced (smoke)
-configs — the same code path the production mesh lowers in dryrun.py, with
-checkpointing, preemption guard and deterministic restart.
+Runs REAL steps, by default on the reduced (smoke) configs — the same code
+path the production mesh lowers in dryrun.py, with checkpointing,
+preemption guard and deterministic restart. ``--full-config`` keeps the
+published widths; ``--layers`` then cuts depth only. Attention takes the
+Pallas kernels on a TPU and the scan mirror elsewhere.
 
   PYTHONPATH=src python -m repro.launch.train --arch yi-9b --steps 50 \
-      --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
+      --batch 8 --seq 128 --ckpt-dir ckpt
 
 XLA latency-hiding / async-collective flags for real TPU runs are set here
 (they are harmless no-ops on CPU).
@@ -33,6 +35,8 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.configs import registry as REG        # noqa: E402
 from repro.configs.base import ShapeConfig       # noqa: E402
+from repro.kernels.tri_attn import ops as OPS    # noqa: E402
+from repro.launch.compile_cache import place_compile_cache  # noqa: E402
 from repro.train import checkpoint as CKPT       # noqa: E402
 from repro.train import data as DATA             # noqa: E402
 from repro.train import fault_tolerance as FT    # noqa: E402
@@ -44,7 +48,10 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-9b", choices=REG.ARCH_IDS)
     ap.add_argument("--full-config", action="store_true",
-                    help="use the full-scale config (TPU pod only)")
+                    help="published widths (one chip: cut depth with "
+                         "--layers)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="with --full-config: keep this many layers")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -59,8 +66,8 @@ def main(argv=None):
                          "local data mesh (parallel/compression.py)")
     args = ap.parse_args(argv)
 
-    cfg = (REG.get_config(args.arch) if args.full_config
-           else REG.smoke_config(args.arch))
+    place_compile_cache()
+    cfg = REG.launch_config(args.arch, args.full_config, args.layers)
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
     opt = OPT.OptConfig(lr=args.lr, warmup_steps=max(2, args.steps // 10),
                         total_steps=args.steps)
@@ -81,7 +88,8 @@ def main(argv=None):
     ds = DATA.SyntheticLM(cfg, shape, seed=args.seed,
                           act_dtype=jnp.float32)
     step_fn = jax.jit(TS.make_train_step(
-        cfg, opt, microbatches=args.microbatches, attn_impl="scan",
+        cfg, opt, microbatches=args.microbatches,
+        attn_impl=OPS.platform_impl(),
         remat=True, compressed_allreduce=compressed_ar),
         donate_argnums=(0,))
 

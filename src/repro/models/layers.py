@@ -121,28 +121,31 @@ def attention(params, x, cfg, *, positions, prefix: int = 0,
     if packed is not None:
         # Ragged multi-request prefill: one launch over the packed grid;
         # member schedules carry each request's window/prefix.
-        ot = attn_ops.packed_prefill_attention(
-            qt, kt, vt, packed,
-            impl="pallas" if attn_impl == "pallas" else "scan")
-        # same checkpoint name as the per-domain path so the training-mode
-        # remat policy can save the context across the packed VJP too
-        ctx = jax.ad_checkpoint.checkpoint_name(
-            ot.transpose(0, 2, 1, 3).reshape(b, s, h * hd), "attn_out")
-        return ctx @ params["wo"], k, v
-    blk = block
-    while s % blk:
-        blk //= 2
-    if attn_impl == "ref" or s <= blk:  # single tile: oracle is cheapest
-        ot = attn_ref.mha_reference(qt, kt, vt, window=cfg.sliding_window,
-                                    prefix=prefix)
+        def attend(q_, k_, v_):
+            return attn_ops.packed_prefill_attention(q_, k_, v_, packed,
+                                                     impl=attn_impl)
     else:
-        ot = attn_ops.triangular_attention(
-            qt, kt, vt, window=cfg.sliding_window, prefix=prefix,
-            impl=attn_impl, block_q=blk, block_k=blk)
+        blk = block
+        while s % blk:
+            blk //= 2
+
+        def attend(q_, k_, v_):
+            return attn_ops.triangular_attention(
+                q_, k_, v_, window=cfg.sliding_window, prefix=prefix,
+                impl=attn_impl, block_q=blk, block_k=blk)
+    if qkv_sh is not None and attn_impl == "pallas":
+        # GSPMD cannot partition a Mosaic kernel: run it per shard of the
+        # layout the hint pins (whole heads and batch rows per device —
+        # attention never mixes either, so no collective is needed).
+        attend = jax.shard_map(attend, mesh=qkv_sh.mesh,
+                               in_specs=(qkv_sh.spec,) * 3,
+                               out_specs=qkv_sh.spec, check_vma=False)
+    ot = attend(qt, kt, vt)
+    # one checkpoint name for both paths so the training-mode remat policy
+    # can save the context across the attention VJP
     ctx = jax.ad_checkpoint.checkpoint_name(
         ot.transpose(0, 2, 1, 3).reshape(b, s, h * hd), "attn_out")
-    out = ctx @ params["wo"]
-    return out, k, v
+    return ctx @ params["wo"], k, v
 
 
 def _decode_qkv(params, x, cfg, cache_k, cache_v, pos):

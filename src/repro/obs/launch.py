@@ -20,7 +20,7 @@ Semantics under jit: the wrapper body runs at TRACE time (once per
 compile), so events fired from inside a jitted program are tagged
 ``phase="trace"`` — launch *geometry* is static per compile, which is
 exactly the quantity the paper compares. Eager launches (direct op calls,
-interpret-mode benchmarks) are tagged ``phase="eager"`` and fire per
+CPU-interpreted benchmarks) are tagged ``phase="eager"`` and fire per
 call. Runtime per-round accounting (decode tiles vs pad-to-max) stays
 with the engine's registry-backed counters, which see host-side truth.
 
@@ -297,14 +297,27 @@ def kernel_summary(registry=None) -> dict:
 def instrumented_pallas_call(kernel_fn, *, meta: LaunchMeta, **pallas_kw):
     """The repo's single ``pl.pallas_call`` site. Same signature contract
     as pallas_call (grid/grid_spec/in_specs/out_specs/... forwarded
-    verbatim); the returned callable records the launch before running."""
+    verbatim, except ``interpret``, which is decided here); the returned
+    callable records the launch before running.
+
+    Compiled or interpreted is resolved from the LOWERING platform by
+    ``jax.lax.platform_dependent``: a program lowered for a TPU runs the
+    Mosaic kernel, and any other platform (the CPU test suite) runs the
+    Pallas interpreter. Only the branch of the lowering platform is
+    lowered, so a TPU program can never reach the interpreter and a CPU
+    program never asks Mosaic. Deciding from the lowering platform rather
+    than ``jax.default_backend()`` also holds when a CPU host compiles for
+    a described TPU topology."""
+    import jax
     from jax.experimental import pallas as pl
 
-    inner = pl.pallas_call(kernel_fn, **pallas_kw)
+    compiled = pl.pallas_call(kernel_fn, interpret=False, **pallas_kw)
+    interpreted = pl.pallas_call(kernel_fn, interpret=True, **pallas_kw)
 
     def launch(*operands):
         record_launch(meta, operands)
-        return inner(*operands)
+        return jax.lax.platform_dependent(*operands, tpu=compiled,
+                                          default=interpreted)
 
     return launch
 

@@ -31,8 +31,6 @@ import json
 import re
 from typing import Dict, List, Optional, Tuple
 
-from repro.launch import compat
-
 _DTYPE_BYTES = {
     "pred": 1, "s4": 0.5, "u4": 0.5, "s8": 1, "u8": 1,
     "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -317,11 +315,18 @@ _FRAME_RE = re.compile(r"stack_frame_id=(\d+)")
 
 # Kernel-fusable interiors, identified by op_name: the scan-attention cell
 # (stack-frame tables are unreliable: custom_vjp re-staging collapses
-# source info) plus the explicit jax.named_scope markers around scan
-# fallbacks with a Pallas twin. The per-JAX-version spellings live in ONE
-# tested table — launch/compat.KERNEL_REGION_OP_NAME_SPELLINGS — shared by
-# every HLO consumer.
-_KERNEL_REGION_RE = compat.kernel_region_regex()
+# source info) — the only model code shaped as vmap(vmap(<cell with
+# lax.scan>)), spelled "vmap(vmap())/.../while" — plus the explicit
+# jax.named_scope markers around scan fallbacks with a Pallas twin. One
+# tested table, so a JAX upgrade that reshuffles a spelling fails a
+# pinned test instead of silently zeroing the interior-bytes column.
+KERNEL_REGION_OP_NAME_SPELLINGS = (
+    r"vmap\(vmap\(\)\)[^\"]*while",
+    r"ssm_scan_kernel",
+    r"wkv_scan_kernel",
+    r"tri_attn_kernel",
+)
+_KERNEL_REGION_RE = re.compile("|".join(KERNEL_REGION_OP_NAME_SPELLINGS))
 
 
 def _op_bytes(op: Op, sym: Dict[str, Op]) -> float:
@@ -436,9 +441,7 @@ def analyze_compiled(compiled) -> dict:
     """Full report for a jax compiled artifact: parser + XLA's own stats."""
     out = analyze(compiled.as_text())
     try:
-        from repro.launch.compat import cost_analysis_dict
-
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         out["xla_cost_analysis"] = {
             "flops": float(ca.get("flops", -1.0)),
             "bytes_accessed": float(ca.get("bytes accessed", -1.0)),

@@ -1,8 +1,8 @@
-"""Three-term roofline model for TPU v5e.
+"""Three-term roofline model, with per-chip peaks keyed by device kind.
 
-  compute_s    = flops_per_device / PEAK_FLOPS
-  memory_s     = hbm_bytes_per_device / HBM_BW
-  collective_s = collective_bytes_per_device / ICI_BW
+  compute_s    = flops_per_device / peak FLOP/s
+  memory_s     = hbm_bytes_per_device / HBM bytes/s
+  collective_s = collective_bytes_per_device / ICI bytes/s per link
 
 (All inputs from roofline/hlo_parse.py are per-device, so dividing by
 per-chip peaks equals the brief's global/(chips*peak) formulation.)
@@ -18,10 +18,34 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional
 
-# TPU v5e, per chip
-PEAK_FLOPS = 197e12     # bf16 FLOP/s
-HBM_BW = 819e9          # bytes/s
-ICI_BW = 50e9           # bytes/s per link (brief's constant)
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float    # bf16 FLOP/s
+    hbm_bw: float   # HBM bytes/s
+    ici_bw: float   # interconnect bytes/s per link
+    source: str
+
+
+# Keyed by jax.Device.device_kind. A chip missing here is an error, never a
+# default: its numbers would be read against another chip's peaks.
+V5E = "TPU v5 lite"
+PEAKS = {
+    V5E: ChipPeaks(
+        flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s; 50 GB/s per ICI link is the "
+               "dry-run brief's per-link figure"),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """Published per-chip peaks of ``device_kind``; KeyError if unknown."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them to roofline.model.PEAKS "
+                       f"with their source") from None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -29,6 +53,7 @@ class RooflineTerms:
     compute_s: float
     memory_s: float
     collective_s: float
+    peak_flops: float
     model_flops: float = 0.0
     hlo_flops_per_dev: float = 0.0
     n_chips: int = 1
@@ -64,7 +89,8 @@ class RooflineTerms:
         """Model FLOPs utilization if the step ran at the dominant term."""
         if self.bound_s == 0:
             return 0.0
-        return (self.model_flops / (self.n_chips * PEAK_FLOPS)) / self.bound_s
+        return (self.model_flops / (self.n_chips * self.peak_flops)) \
+            / self.bound_s
 
     @property
     def bound_kernel_adj_s(self) -> float:
@@ -76,7 +102,7 @@ class RooflineTerms:
     def mfu_at_bound_kernel_adj(self) -> float:
         if self.bound_kernel_adj_s == 0:
             return 0.0
-        return (self.model_flops / (self.n_chips * PEAK_FLOPS)) \
+        return (self.model_flops / (self.n_chips * self.peak_flops)) \
             / self.bound_kernel_adj_s
 
     def as_dict(self) -> dict:
@@ -96,14 +122,16 @@ class RooflineTerms:
         }
 
 
-def terms_from_analysis(an: dict, *, n_chips: int,
+def terms_from_analysis(an: dict, *, n_chips: int, device_kind: str,
                         model_flops: float = 0.0) -> RooflineTerms:
+    pk = peaks(device_kind)
     return RooflineTerms(
-        compute_s=an["flops"] / PEAK_FLOPS,
-        memory_s=an["hbm_bytes"] / HBM_BW,
+        compute_s=an["flops"] / pk.flops,
+        memory_s=an["hbm_bytes"] / pk.hbm_bw,
         memory_kernel_adj_s=an.get("hbm_bytes_kernel_adj",
-                                   an["hbm_bytes"]) / HBM_BW,
-        collective_s=an["collective_bytes_total"] / ICI_BW,
+                                   an["hbm_bytes"]) / pk.hbm_bw,
+        collective_s=an["collective_bytes_total"] / pk.ici_bw,
+        peak_flops=pk.flops,
         model_flops=model_flops,
         hlo_flops_per_dev=an["flops"],
         n_chips=n_chips,

@@ -66,6 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import mapping as M
+from repro.kernels.tri_attn import ops as attn_ops
 from repro.models import model as MD
 from repro.obs import metrics as MET
 from repro.obs import schema as SCH
@@ -126,11 +127,11 @@ class Engine:
     """In-process engine; submit() then run() until drained."""
 
     def __init__(self, params, cfg, *, slots: int = 4, max_len: int = 512,
-                 cache_dtype=jnp.float32, temperature: float = 0.0,
+                 cache_dtype=None, temperature: float = 0.0,
                  seed: int = 0, prefill_mode: str = "packed",
-                 prefill_block: int = 16, prefill_impl: str = "scan",
+                 prefill_block: int = 16, prefill_impl: Optional[str] = None,
                  prefill_bucket: int = 0, decode_mode: str = "auto",
-                 decode_block: int = 16, decode_impl: str = "scan",
+                 decode_block: int = 16, decode_impl: Optional[str] = None,
                  step_mode: str = "split", auto_cost_measure: bool = False,
                  admit_order: str = "cost", stats_log_rounds: int = 1024,
                  fault_plan: Optional[F.FaultPlan] = None, clock=None,
@@ -140,6 +141,11 @@ class Engine:
                  traced_max_lam: Optional[int] = None,
                  guard_output: bool = True,
                  escalate_step_errors: bool = False):
+        # unset choices follow what the engine can observe: the cache holds
+        # the model's own dtype, and attention runs the Pallas kernels on a
+        # TPU and the scan mirror elsewhere (ops.platform_impl)
+        if cache_dtype is None:
+            cache_dtype = MD.param_dtype(cfg)
         # ctor kwargs as REQUESTED (pre-downgrade), for snapshot/restore;
         # fault_plan/clock/retry are runtime harness, supplied at restore.
         self._init_kw = dict(
@@ -155,6 +161,8 @@ class Engine:
             traced_max_lam=traced_max_lam, guard_output=guard_output)
         self.params, self.cfg = params, cfg
         self.B, self.max_len = slots, max_len
+        prefill_impl = prefill_impl or attn_ops.platform_impl()
+        decode_impl = decode_impl or attn_ops.platform_impl()
         self.cache = MD.init_cache(cfg, slots, max_len, cache_dtype)
         self.pos = jnp.zeros((slots,), jnp.int32)
         self.last_tok = jnp.zeros((slots, 1), jnp.int32)

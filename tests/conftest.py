@@ -2,20 +2,12 @@
 and benches must see the single real CPU device; only launch/dryrun.py forces
 512 placeholder devices (in its own process).
 
-Offline-test compat policy: the suite must collect and pass with no network
-and no optional deps. `_hypo_compat.install()` registers a fixed-seed
-stand-in for `hypothesis` when the real package is absent (real hypothesis
-is used untouched when available)."""
+Offline-test policy: the suite must collect and pass with no network."""
 
 import gc
 
 import pytest
-
-import _hypo_compat
-
-_HAVE_REAL_HYPOTHESIS = _hypo_compat.install()
-
-from hypothesis import HealthCheck, settings  # noqa: E402 (after install)
+from hypothesis import HealthCheck, settings
 
 settings.register_profile(
     "repro",

@@ -14,7 +14,7 @@ from hypothesis import given, strategies as st
 
 from repro.configs import registry as REG
 from repro.configs.base import ShapeConfig
-from repro.launch.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.train import checkpoint as CKPT
 from repro.train import data as DATA
 from repro.train import fault_tolerance as FT

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 from hypothesis import given, settings, strategies as st
 
-from repro.launch.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.parallel import compression as C
 
 

@@ -1,7 +1,6 @@
 """Packed mixed-position decode == sequential per-slot decode.
 
-Property-tests (hypothesis, shimmed offline by tests/_hypo_compat.py) the
-tentpole claim end to end:
+Property-tests (hypothesis) the tentpole claim end to end:
 
   * ops level — packed_decode_attention (scan / pallas / ref impls) equals
     the isolated per-slot oracle for arbitrary skewed KV lengths, retired
@@ -87,20 +86,27 @@ def test_property_random_rounds_match_oracle(data):
 
 def test_capacity_padding_is_inert():
     """Bigger static capacity buckets only add masked pad steps: output
-    identical across capacities (the recompile-avoidance contract)."""
+    identical across capacities (the recompile-avoidance contract).
+
+    The contract is exact WITHIN each implementation; scan and Pallas
+    accumulate in different orders, so across them it is the attention
+    tolerance."""
     b, blk, s_cache = 3, 4, 32
     kv_lens, slots = [9, 30], [0, 2]
     q, kc, vc, tbl, cap, want = _round(kv_lens, slots, b, blk, s_cache)
-    outs = []
-    for capacity in (cap, cap + 5, 4 * cap):
-        for impl in ("scan", "pallas"):
+    outs = {}
+    for impl in ("scan", "pallas"):
+        for capacity in (cap, cap + 5, 4 * cap):
             spec = OPS.DecodeRoundSpec(n_members=b + 1, capacity=capacity,
                                        blk=blk, impl=impl)
-            outs.append(np.asarray(OPS.packed_decode_attention(
-                q, kc, vc, jnp.asarray(tbl), spec)))
-    for o in outs[1:]:
-        np.testing.assert_array_equal(outs[0], o)
-    O.assert_close(outs[0], want, "attn")
+            outs.setdefault(impl, []).append(np.asarray(
+                OPS.packed_decode_attention(q, kc, vc, jnp.asarray(tbl),
+                                            spec)))
+    for per_cap in outs.values():
+        for o in per_cap[1:]:
+            np.testing.assert_array_equal(per_cap[0], o)
+    O.assert_close(outs["pallas"][0], outs["scan"][0], "attn")
+    O.assert_close(outs["scan"][0], want, "attn")
 
 
 def test_decode_table_layout():

@@ -33,7 +33,7 @@ def test_elastic_restore_onto_8_device_mesh(tmp_path):
         import jax, jax.numpy as jnp
         from repro.configs import registry as REG
         from repro.configs.base import ShapeConfig
-        from repro.launch.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.parallel import sharding as SH
         from repro.train import checkpoint as CKPT
         from repro.train import data as DATA

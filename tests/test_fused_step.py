@@ -13,8 +13,8 @@ The tentpole claim, verified at every layer:
     absorbs every fused-attempt failure without changing the streams;
   * capacity — a pinned grid the round outgrew rebuckets (schema-valid
     degrade, satellite of PR 8's bare-assert bugfix) instead of crashing;
-  * compat — the HLO kernel-region op_name spellings live in ONE tested
-    table (launch/compat) shared with roofline/hlo_parse.
+  * the HLO kernel-region op_name spellings live in ONE tested table
+    (roofline/hlo_parse).
 """
 
 import jax
@@ -25,7 +25,6 @@ import pytest
 import oracles as O
 from repro.configs import registry as REG
 from repro.kernels.tri_attn import ops as OPS
-from repro.launch import compat as C
 from repro.models import model as MD
 from repro.resilience import faults as F
 from repro.serve import decode as D
@@ -301,26 +300,26 @@ def test_engine_fused_requires_attention_only():
 
 
 # ---------------------------------------------------------------------------
-# compat: kernel-region op_name spellings live in ONE tested table
+# kernel-region op_name spellings live in ONE tested table
 # ---------------------------------------------------------------------------
 
 
 def test_kernel_region_spellings_pinned():
-    """Satellite: both per-JAX-version spellings of the scan-attention
-    cell — "vmap(vmap())/.../while" (new) and "vmap(vmap(while))"
-    (0.4.x) — are in launch/compat's table, and roofline/hlo_parse builds
-    its regex from that table (no ad-hoc copy to drift)."""
+    """The installed JAX's spelling of the scan-attention cell,
+    "vmap(vmap())/.../while", and the scan-fallback markers are in
+    roofline/hlo_parse's table, and its regex is built from that table
+    (no ad-hoc copy to drift)."""
     from repro.roofline import hlo_parse as H
 
-    r = C.kernel_region_regex()
+    r = H._KERNEL_REGION_RE
     assert r.search('op_name="jit(f)/vmap(vmap())/while/body/add"')
-    assert r.search('op_name="vmap(vmap(while))"')
     for marker in ("ssm_scan_kernel", "wkv_scan_kernel",
                    "tri_attn_kernel"):
-        assert any(marker in s for s in
-                   C.KERNEL_REGION_OP_NAME_SPELLINGS)
+        assert any(marker in s for s in H.KERNEL_REGION_OP_NAME_SPELLINGS)
         assert r.search(marker)
-    # near-misses must NOT match (a plain while loop is not a kernel cell)
+    # near-misses must NOT match (a plain while loop is not a kernel cell),
+    # nor does JAX 0.4.x's collapsed spelling, which no supported JAX emits
     assert not r.search('op_name="jit(f)/while/body/add"')
     assert not r.search('op_name="vmap(while)"')
-    assert H._KERNEL_REGION_RE.pattern == r.pattern
+    assert not r.search('op_name="vmap(vmap(while))"')
+    assert r.pattern == "|".join(H.KERNEL_REGION_OP_NAME_SPELLINGS)

@@ -7,8 +7,8 @@ Property-tests the training tentpole end to end:
     paths, for mixed ltm/band/prefix members (no fallback: the Pallas
     grad jaxpr contains the packed fwd + dq + dkv pallas_calls and no
     scan loop);
-  * property (hypothesis, shimmed offline) — packed-batch grads equal
-    per-document sequential grads for random member mixes;
+  * property (hypothesis) — packed-batch grads equal per-document
+    sequential grads for random member mixes;
   * train level — a packed ragged-document train step produces the SAME
     loss and parameter gradients as the pad-to-max padded batch over the
     identical documents, and make_train_step(packed=...) steps cleanly;
@@ -63,16 +63,21 @@ def test_packed_grad_matches_f64_oracle(impl):
 
 def test_pallas_grad_runs_packed_bwd_not_fallback():
     """The Pallas path's backward is the packed dq + dk/dv kernels: the
-    grad jaxpr carries three pallas_call equations (fwd, dq, dkv) and no
-    lax.scan fallback loop."""
+    grad jaxpr carries three pallas_call launches (fwd, dq, dkv) on the
+    TPU branch, none of them interpreted there, and no lax.scan fallback
+    loop."""
+    from repro.analysis import jaxpr_lint as JL
+
     s = sum(LENS)
     q, k, v = O.rand_qkv(1, 1, 2, 1, s, 8)
     psched = OPS.make_packed_sched(LENS, block=BLK)
-    jaxpr = str(jax.make_jaxpr(jax.grad(
+    closed = jax.make_jaxpr(jax.grad(
         lambda q_: jnp.sum(OPS.packed_prefill_attention(
-            q_, k, v, psched, impl="pallas"))))(q))
-    assert jaxpr.count("pallas_call") == 3, jaxpr.count("pallas_call")
-    assert "scan[" not in jaxpr
+            q_, k, v, psched, impl="pallas"))))(q)
+    n_pc = JL.count_primitive(closed.jaxpr, "pallas_call")
+    assert n_pc == 3, n_pc
+    assert JL.interpreted_on_tpu(closed.jaxpr) == 0
+    assert "scan[" not in str(closed)
 
 
 @given(st.data())

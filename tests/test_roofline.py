@@ -37,10 +37,8 @@ def test_scan_multiplies_by_trip_count():
     assert an["unknown_trip_loops"] == 0
     # XLA's own cost_analysis counts the body once — this is the bug the
     # parser exists to fix; keep the regression visible:
-    from repro.launch.compat import cost_analysis_dict
-
     comp = jax.jit(f).lower(x, w).compile()
-    xla_flops = cost_analysis_dict(comp).get("flops", 0.0)
+    xla_flops = comp.cost_analysis().get("flops", 0.0)
     assert xla_flops <= an["flops"] / 16
 
 
@@ -92,13 +90,22 @@ def test_tuple_shape_bytes():
 def test_roofline_terms_and_dominance():
     an = {"flops": 197e12, "hbm_bytes": 819e9 / 2,
           "collective_bytes_total": 50e9 / 4}
-    t = RF.terms_from_analysis(an, n_chips=4, model_flops=4 * 197e12 / 2)
+    t = RF.terms_from_analysis(an, n_chips=4, device_kind="TPU v5 lite",
+                               model_flops=4 * 197e12 / 2)
     assert t.dominant == "compute"
     assert abs(t.compute_s - 1.0) < 1e-9
     assert abs(t.memory_s - 0.5) < 1e-9
     assert abs(t.collective_s - 0.25) < 1e-9
     assert abs(t.useful_flops_ratio - 0.5) < 1e-9
     assert abs(t.mfu_at_bound - 0.5) < 1e-9
+
+
+def test_roofline_peaks_refuse_an_unknown_device_kind():
+    assert RF.peaks("TPU v5 lite").flops == 197e12
+    with pytest.raises(KeyError, match="no published peaks"):
+        RF.terms_from_analysis({"flops": 1.0, "hbm_bytes": 1.0,
+                                "collective_bytes_total": 0.0},
+                               n_chips=1, device_kind="cpu")
 
 
 def test_model_flops_train_vs_decode():

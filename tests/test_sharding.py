@@ -12,7 +12,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import registry as REG
-from repro.launch.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.parallel import sharding as SH
 
 
@@ -47,7 +47,7 @@ _SUBPROC = textwrap.dedent("""
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import registry as REG
-    from repro.launch.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.parallel import sharding as SH
 
     mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))

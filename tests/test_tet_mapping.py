@@ -53,15 +53,6 @@ def test_tet_host_roundtrip_large(lam):
     assert M.tet_inverse(i, j, k) == lam
 
 
-@given(st.integers(min_value=1, max_value=50))
-def test_given_coexists_with_fixtures(tmp_path, n):
-    """Regression for the offline hypothesis shim: strategy values must
-    bind to the RIGHTMOST parameters by name, leaving pytest fixtures
-    (passed as kwargs) intact. Also passes under real hypothesis."""
-    assert tmp_path.exists()
-    assert 1 <= n <= 50
-
-
 def test_tet_plane_major_contiguity():
     # Plane i occupies lambdas [tet(i), tet(i+1)), enumerated by g(mu):
     # the 3D analogue of LTM's row-major contiguity.
