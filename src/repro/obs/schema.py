@@ -125,6 +125,12 @@ def validate_event(ev: dict, *, envelope: bool = True) -> List[str]:
         for field, ftype in _SPAN_FIELDS.items():
             _check(errors, isinstance(ev.get(field), ftype),
                    f"span.{field} missing or not {ftype}: {ev.get(field)!r}")
+        t0, t1 = ev.get("t0_ns"), ev.get("t1_ns")
+        if t0 is not None or t1 is not None:
+            _check(errors, isinstance(t0, int) and isinstance(t1, int)
+                   and 0 <= t0 <= t1,
+                   f"span.t0_ns / t1_ns must be ints, 0 <= t0_ns <= t1_ns: "
+                   f"{t0!r}, {t1!r}")
     elif etype == "degrade":
         for field, ftype in _DEGRADE_FIELDS.items():
             _check(errors, isinstance(ev.get(field), ftype),

@@ -11,7 +11,13 @@ emit two things on close:
   * a ``span`` event to the trace sinks (obs/sinks.py JSONL schema),
   * a ``span_ms`` histogram observation labeled by span name.
 
-Wall clock is host ``time.perf_counter``. Because JAX dispatch is async,
+Durations come from host ``time.perf_counter``. Each span event also
+carries ``t0_ns`` / ``t1_ns`` from ``time.time_ns()``, the wall clock the
+``jax.profiler`` host tracer stamps its events with, taken inside the
+span's ``TraceAnnotation``: a JSONL span lines up with the same region in
+a device profile (an ``.xplane.pb`` holds its host events relative to the
+``profile_start_time`` stat of its "Task Environment" plane, on that same
+clock). Because JAX dispatch is async,
 a span around a jitted call measures *dispatch* unless the result is
 attached: ``sp.attach(x)`` registers pytrees to ``jax.block_until_ready``
 immediately before the end timestamp, so the span covers device work —
@@ -29,6 +35,7 @@ import time
 from typing import Any, List, Optional
 
 from repro.obs import metrics as MET
+from repro.obs import sinks as SK
 
 _tls = threading.local()
 
@@ -59,6 +66,7 @@ class Span:
         self._sync: List[Any] = []
         self._annotation = None
         self.t0 = self.t1 = None
+        self.t0_ns = self.t1_ns = None
 
     # -- lifecycle (driven by the ``span`` context manager) ------------------
     def _open(self):
@@ -70,6 +78,7 @@ class Span:
             self._annotation.__enter__()
         except Exception:
             self._annotation = None
+        self.t0_ns = time.time_ns()
         self.t0 = time.perf_counter()
         return self
 
@@ -82,6 +91,7 @@ class Span:
             except Exception:
                 pass
         self.t1 = time.perf_counter()
+        self.t1_ns = time.time_ns()
         if self._annotation is not None:
             try:
                 self._annotation.__exit__(None, None, None)
@@ -92,9 +102,8 @@ class Span:
             st.pop()
         MET.histogram_observe("span_ms", self.duration_ms,
                               labels={"name": self.name})
-        from repro.obs import sinks as SK
-
-        SK.emit_event(self.as_event())
+        if SK.trace_enabled():
+            SK.emit_event(self.as_event())
 
     # -- user API ------------------------------------------------------------
     def attach(self, *values):
@@ -114,7 +123,8 @@ class Span:
     def as_event(self) -> dict:
         ev = {"type": "span", "name": self.name, "path": self.path,
               "parent": self.parent, "depth": self.depth,
-              "duration_ms": self.duration_ms}
+              "duration_ms": self.duration_ms,
+              "t0_ns": self.t0_ns, "t1_ns": self.t1_ns}
         if self.attrs:
             ev["attrs"] = _plain(self.attrs)
         if self.error is not None:

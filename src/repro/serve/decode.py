@@ -109,13 +109,15 @@ def poisoned_slots(logits_np: np.ndarray, live: Sequence[int]) -> List[int]:
             if not bool(np.isfinite(logits_np[s]).all())]
 
 
-def states_finite(states) -> bool:
+def states_finite(states, to_host) -> bool:
     """NaN/Inf guard over packed prefill state leaves (float leaves only;
-    token/table int leaves can't be poisoned by arithmetic)."""
+    token/table int leaves can't be poisoned by arithmetic). Each float
+    leaf costs one blocking read, made as ``to_host(x, what, bool)``
+    (``Engine._to_host``, which counts it)."""
     for leaf in jax.tree.leaves(states):
         if not jnp.issubdtype(leaf.dtype, jnp.floating):
             continue
-        if not bool(jnp.isfinite(leaf).all()):
+        if not to_host(jnp.isfinite(leaf).all(), "states_finite", bool):
             return False
     return True
 

@@ -58,6 +58,7 @@ every submitted request ends in exactly one terminal status
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, List, Optional
 
@@ -112,6 +113,25 @@ class Request:
 # In particular a uniform all-live batch (skew=1, equal grids) always
 # stays lockstep — the old any-skew test sent it packed and lost 2.3x.
 PACKED_TILE_COST_RATIO = 2.3
+
+# The spans of one engine round (obs.trace), outermost first. Every host
+# phase of Engine.round() runs under one of them, so the idle gaps of a
+# device profile fall under the phase that left the device waiting.
+# engine.to_host wraps each blocking device-to-host read: its count in
+# span_ms{name=engine.to_host} is the number of host syncs.
+SPANS = ("engine.round", "engine.schedule", "engine.admit_batch",
+         "engine.admit_commit", "engine.decode_plan", "engine.decode_round",
+         "engine.fused_step", "engine.guard", "engine.sample",
+         "engine.commit", "engine.to_host")
+
+_f32_copy = functools.partial(np.array, dtype=np.float32)
+
+
+def _poison(states):
+    """NaN in every float leaf: the corruption a FaultPlan injects."""
+    return jax.tree.map(
+        lambda x: jnp.full_like(x, jnp.nan)
+        if jnp.issubdtype(x.dtype, jnp.floating) else x, states)
 
 
 class EngineStepError(RuntimeError):
@@ -191,7 +211,7 @@ class Engine:
         self.decode_mode = decode_mode if attn_only else "lockstep"
         self.decode_impl = decode_impl
         # fused continuous batching: admits AND live decode slots advance
-        # in ONE mixed packed launch per engine step (step_fused). Needs
+        # in ONE mixed packed launch per engine step (_step_fused). Needs
         # splice-able attention mixers, same as packed prefill.
         assert step_mode in ("split", "fused")
         self.step_mode = step_mode if attn_only else "split"
@@ -295,6 +315,13 @@ class Engine:
         metrics.json carries."""
         self.registry.counter_inc(name, value)
         MET.counter_inc(name, value)
+
+    def _to_host(self, x, what: str, read=np.asarray):
+        """Every blocking device-to-host read of a round goes through here,
+        as ``read(x)`` under an ``engine.to_host`` span (attrs ``what``,
+        ``nbytes``): the count of those spans is the round's host syncs."""
+        with TR.span("engine.to_host", what=what, nbytes=int(x.nbytes)):
+            return read(x)
 
     @property
     def stats(self) -> dict:
@@ -539,46 +566,47 @@ class Engine:
                 self.params, self.cfg, prompts, block=self.prefill_block,
                 attn_impl=impl, bucket=self.prefill_bucket)
             sp.attach(states)
-        if self.fault_plan is not None and \
-                self.fault_plan.poisons_admit(rnd):
-            # injected corruption lands at the host boundary the guard
-            # below inspects — the detection path is the real one.
-            states = jax.tree.map(
-                lambda x: jnp.full_like(x, jnp.nan)
-                if jnp.issubdtype(x.dtype, jnp.floating) else x, states)
-        if self.guard_output and not D.states_finite(states):
-            raise F.PoisonedOutput(
-                f"admit round {rnd}: non-finite packed prefill states")
-        # commit
-        self._inc("prefill_launches")
-        self._inc("prefill_requests", len(pairs))
-        self._inc("prefill_tokens", sum(lens))
-        for (slot, req), start, length in zip(pairs, starts, lens):
-            self._splice_slot(slot, states, start, length)
-            self.last_tok = self.last_tok.at[slot, 0].set(
-                int(req.feed[-1]))
-            self.pos = self.pos.at[slot].set(length - 1)
-            self.slot_req[slot] = req
-            self.remaining[slot] = req.max_new - len(req.out)
+        with TR.span("engine.admit_commit"):
+            if self.fault_plan is not None and \
+                    self.fault_plan.poisons_admit(rnd):
+                # injected corruption lands at the host boundary the guard
+                # below inspects — the detection path is the real one.
+                states = _poison(states)
+            if self.guard_output and not D.states_finite(states,
+                                                         self._to_host):
+                raise F.PoisonedOutput(
+                    f"admit round {rnd}: non-finite packed prefill states")
+            self._inc("prefill_launches")
+            self._inc("prefill_requests", len(pairs))
+            self._inc("prefill_tokens", sum(lens))
+            for (slot, req), start, length in zip(pairs, starts, lens):
+                self._splice_slot(slot, states, start, length)
+                self.last_tok = self.last_tok.at[slot, 0].set(
+                    int(req.feed[-1]))
+                self.pos = self.pos.at[slot].set(length - 1)
+                self.slot_req[slot] = req
+                self.remaining[slot] = req.max_new - len(req.out)
 
     def _admit_sequential(self, pairs, rnd: int):
         """Per-request sequential prefill (host-map path): each request
         is retried and, on exhaustion, failed INDIVIDUALLY — one bad
         request cannot take down its round-mates."""
-        for member, (slot, req) in enumerate(pairs):
-            def one(attempt, s=slot, r=req, m=member):
-                if self.fault_plan is not None:
-                    self._sleep(self.fault_plan.maybe_fail(
-                        "admit", rnd, member=m))
-                self._prefill_into_slot(s, r)
+        with TR.span("engine.admit_batch", requests=len(pairs),
+                     mode="sequential"):
+            for member, (slot, req) in enumerate(pairs):
+                def one(attempt, s=slot, r=req, m=member):
+                    if self.fault_plan is not None:
+                        self._sleep(self.fault_plan.maybe_fail(
+                            "admit", rnd, member=m))
+                    self._prefill_into_slot(s, r)
 
-            ok, _, err = self._attempt(one, n_affected=1)
-            if not ok:
-                if self.escalate_step_errors:
-                    # last rung of the admit ladder exhausted on a fleet
-                    # replica: the engine is out of fallbacks — escalate.
-                    raise EngineStepError("admit", rnd, err)
-                self._record_failure(req, "admit", rnd, err)
+                ok, _, err = self._attempt(one, n_affected=1)
+                if not ok:
+                    if self.escalate_step_errors:
+                        # last rung of the admit ladder exhausted on a
+                        # fleet replica: the engine is out of fallbacks.
+                        raise EngineStepError("admit", rnd, err)
+                    self._record_failure(req, "admit", rnd, err)
 
     def _prefill_tiles(self, req: Request) -> int:
         """Packed-prefill cost model for one request: tri(ceil(S / block))
@@ -630,25 +658,48 @@ class Engine:
                         key=lambda s: (self.quarantined[s], s))
             del self.quarantined[first]
 
-    def _admit(self):
+    def _take_admits(self) -> List[tuple]:
+        """Release expired quarantines and pop a queued request for every
+        free slot: this round's (slot, request) admits."""
         self._release_quarantine()
         free = [s for s in range(self.B) if self.slot_req[s] is None
                 and s not in self.quarantined]
         take = min(len(free), len(self.queue))
         if not take:
-            return
+            return []
         reqs = self._pick_requests(take)
-        pairs = list(zip(free, reqs))
         for req in reqs:
             req.status = "running"
+        return list(zip(free, reqs))
+
+    def _log_admit(self, reqs: List[Request]):
         self._inc("admit_rounds")
         self._admit_order_log.append(
             [(r.uid, self._prefill_tiles(r)) for r in reqs])
         self._admit_round_tiles.append(
             sum(self._prefill_tiles(r) for r in reqs))
+
+    def _plan_admit(self, pairs):
+        """Number and log a split admit round; its ladder. None when
+        there is nothing to admit."""
+        if not pairs:
+            return None
+        reqs = [req for _, req in pairs]
+        self._log_admit(reqs)
         rnd = self._admit_round_idx
         self._admit_round_idx += 1
-        stages = self._admit_stages(rnd, reqs)
+        return pairs, rnd, self._admit_stages(rnd, reqs)
+
+    def _admit(self):
+        with TR.span("engine.schedule"):
+            plan = self._plan_admit(self._take_admits())
+        self._run_admit(plan)
+
+    def _run_admit(self, plan):
+        """Launch a planned admit round down its degradation ladder."""
+        if plan is None:
+            return
+        pairs, rnd, stages = plan
 
         def runner(stage, attempt):
             if stage == "sequential":
@@ -710,33 +761,35 @@ class Engine:
         under the decode degradation ladder; emitted logits pass the
         NaN/Inf guard before any state commits, and poisoned slots are
         quarantined + replayed instead of emitting garbage."""
-        active = np.array([r is not None for r in self.slot_req])
-        if not active.any():
-            return
-        live = [s for s in range(self.B) if active[s]]
-        pos_np = np.asarray(self.pos)
-        kv_lens = [int(min(pos_np[s] + 1, self.s_cache)) for s in live]
-        # round geometry (recorded every round, whichever path runs): what
-        # the packed grid covers vs what pad-to-max lockstep would.
-        tiles = [-(-kl // self.decode_block) for kl in kv_lens]
-        # COST CROSSOVER (not the old any-skew test): the packed round
-        # does RATIO times more work per tile than lockstep's fused
-        # einsum, so "auto" goes packed only when pad-to-max waste beats
-        # that premium. Lockstep always launches the full batch
-        # (B * max tiles), so mild skew — or a uniform batch, where the
-        # old test already lost 2.3x by going packed — stays lockstep.
-        ratio = PACKED_TILE_COST_RATIO
-        if self.auto_cost_measure and all(self._mode_cost.values()):
-            ratio = self._mode_cost["packed"] / self._mode_cost["lockstep"]
-        use_packed = self.decode_mode == "packed" or (
-            self.decode_mode == "auto"
-            and ratio * sum(tiles) < self.B * max(tiles))
-        self._inc("decode_rounds")
-        self._inc("decode_tiles_packed", sum(tiles))
-        self._inc("decode_tiles_padded", len(live) * max(tiles))
-        rnd = self._decode_round_idx
-        self._decode_round_idx += 1
-        stages = ["packed", "lockstep"] if use_packed else ["lockstep"]
+        with TR.span("engine.decode_plan"):
+            active = np.array([r is not None for r in self.slot_req])
+            if not active.any():
+                return
+            live = [s for s in range(self.B) if active[s]]
+            pos_np = self._to_host(self.pos, "pos")
+            kv_lens = [int(min(pos_np[s] + 1, self.s_cache)) for s in live]
+            # round geometry (recorded every round, whichever path runs):
+            # what the packed grid covers vs what pad-to-max lockstep would.
+            tiles = [-(-kl // self.decode_block) for kl in kv_lens]
+            # COST CROSSOVER (not the old any-skew test): the packed round
+            # does RATIO times more work per tile than lockstep's fused
+            # einsum, so "auto" goes packed only when pad-to-max waste
+            # beats that premium. Lockstep always launches the full batch
+            # (B * max tiles), so mild skew — or a uniform batch, where the
+            # old test already lost 2.3x by going packed — stays lockstep.
+            ratio = PACKED_TILE_COST_RATIO
+            if self.auto_cost_measure and all(self._mode_cost.values()):
+                ratio = (self._mode_cost["packed"]
+                         / self._mode_cost["lockstep"])
+            use_packed = self.decode_mode == "packed" or (
+                self.decode_mode == "auto"
+                and ratio * sum(tiles) < self.B * max(tiles))
+            self._inc("decode_rounds")
+            self._inc("decode_tiles_packed", sum(tiles))
+            self._inc("decode_tiles_padded", len(live) * max(tiles))
+            rnd = self._decode_round_idx
+            self._decode_round_idx += 1
+            stages = ["packed", "lockstep"] if use_packed else ["lockstep"]
         t0 = float(self.clock())
         try:
             (logits, cache), stage = self._run_ladder(
@@ -766,25 +819,56 @@ class Engine:
             prev = self._mode_cost[stage]
             self._mode_cost[stage] = per_tile if prev is None \
                 else 0.8 * prev + 0.2 * per_tile
-        # NaN/Inf guard at the host boundary (+ injected poison lands in
-        # the same place the guard inspects).
+        with TR.span("engine.guard"):
+            bad = self._guard(logits, rnd, live, "decode")
+        with TR.span("engine.sample"):
+            self.key, k = jax.random.split(self.key)
+            nxt = D.sample_logits(k, logits[:, 0],
+                                  temperature=self.temperature,
+                                  vocab_size=self.cfg.vocab_size)
+            nxt_np = self._to_host(nxt, "tokens")
+        with TR.span("engine.commit"):
+            self.cache = cache
+            adv = active.copy()
+            for slot in bad:
+                adv[slot] = False  # quarantined: state reset at readmission
+            self.pos = self.pos + jnp.asarray(adv, jnp.int32)
+            self.last_tok = nxt[:, None]
+            for slot in range(self.B):
+                req = self.slot_req[slot]
+                if req is None:
+                    continue
+                req.out.append(int(nxt_np[slot]))
+                self.remaining[slot] -= 1
+                if self.remaining[slot] <= 0 or self._to_host(
+                        self.pos[slot], "pos_slot", int) \
+                        >= self.max_len - 1:
+                    self._finish(req, "done")
+                    self.slot_req[slot] = None  # refilled next admit
+
+    def _guard(self, logits, rnd: int, live, kind: str) -> List[int]:
+        """The NaN/Inf guard at the host boundary (where injected poison
+        lands too): the live slots whose emitted logits are non-finite,
+        quarantined, their requests queued for replay. ``logits`` is
+        (B, V), or (B, 1, V) as a decode step returns it."""
         bad: List[int] = []
         if self.guard_output or self.fault_plan is not None:
-            logits_np = np.array(logits[:, 0], np.float32)  # host copy
+            if logits.ndim == 3:
+                logits = logits[:, 0]
+            logits_np = self._to_host(logits, "logits", _f32_copy)
             if self.fault_plan is not None:
                 for s in self.fault_plan.poison_slots(rnd, live):
                     logits_np[s] = np.nan
             if self.guard_output:
                 bad = D.poisoned_slots(logits_np, live)
         if bad and self.escalate_step_errors:
-            # fleet replica: a poisoned round escalates BEFORE any state
-            # commits (no cache/pos/token writes happened yet) — the
-            # fleet quarantines the whole replica instead of this engine
-            # quarantining one slot, and every live request's feed still
-            # excludes the poisoned round, so migration re-prefills the
-            # exact pre-fault state.
+            # fleet replica: a poisoned round escalates BEFORE any token
+            # is appended — the fleet quarantines the whole replica
+            # instead of this engine quarantining one slot, and every live
+            # request's feed still excludes the poisoned round, so
+            # migration re-prefills the exact pre-fault state.
             raise F.PoisonedOutput(
-                f"decode round {rnd}: non-finite logits in slots {bad}")
+                f"{kind} round {rnd}: non-finite logits in slots {bad}")
         replays: List[Request] = []
         for slot in bad:
             req = self.slot_req[slot]
@@ -803,34 +887,16 @@ class Engine:
             # requests next round, prefilled on prompt + emitted tokens
             # (Request.feed) into a healthy slot.
             self.queue[0:0] = replays
-        self.key, k = jax.random.split(self.key)
-        nxt = D.sample_logits(k, logits[:, 0], temperature=self.temperature,
-                              vocab_size=self.cfg.vocab_size)
-        nxt_np = np.asarray(nxt)
-        self.cache = cache
-        adv = active.copy()
-        for slot in bad:
-            adv[slot] = False  # quarantined: state reset at readmission
-        self.pos = self.pos + jnp.asarray(adv, jnp.int32)
-        self.last_tok = nxt[:, None]
-        for slot in range(self.B):
-            req = self.slot_req[slot]
-            if req is None:
-                continue
-            req.out.append(int(nxt_np[slot]))
-            self.remaining[slot] -= 1
-            if self.remaining[slot] <= 0 or \
-                    int(self.pos[slot]) >= self.max_len - 1:
-                self._finish(req, "done")
-                self.slot_req[slot] = None  # slot freed -> refilled next admit
+        return bad
 
     # -- fused continuous batching -------------------------------------------
-    def step_fused(self):
-        """One FUSED engine round: admit every queued request a free slot
-        can take AND advance every live decode slot, in ONE mixed packed
+    def _step_fused(self, pairs):
+        """One FUSED engine round: admit ``pairs`` (free slot, request)
+        AND advance every live decode slot, in ONE mixed packed
         launch per attention layer (decode.fused_step over the "mixed"
-        schedule kind). Rounds with nothing to admit delegate to step()
-        — the split decode round is already a single launch.
+        schedule kind). round() runs rounds with nothing to admit
+        through step() — the split decode round is already a single
+        launch.
 
         Any failure inside the fused attempt (injected fault, poisoned
         states, traced-envelope overflow, real launch error) takes the
@@ -838,22 +904,15 @@ class Engine:
         the head and the round re-runs through the split machinery, whose
         own admit/decode ladders then absorb the fault. Greedy decode is
         token-identical either way."""
-        self._release_quarantine()
-        free = [s for s in range(self.B) if self.slot_req[s] is None
-                and s not in self.quarantined]
-        take = min(len(free), len(self.queue))
-        if not take:
-            return self.step()
-        reqs = self._pick_requests(take)
-        pairs = list(zip(free, reqs))
-        for req in reqs:
-            req.status = "running"
+        reqs = [req for _, req in pairs]
         a_rnd = self._admit_round_idx
         d_rnd = self._decode_round_idx
-        live = [s for s in range(self.B) if self.slot_req[s] is not None
-                and s not in [sl for sl, _ in pairs]]
-        pos_np = np.asarray(self.pos)
-        kv_lens = [int(min(pos_np[s] + 1, self.s_cache)) for s in live]
+        with TR.span("engine.decode_plan"):
+            admitted = [sl for sl, _ in pairs]
+            live = [s for s in range(self.B) if self.slot_req[s] is not None
+                    and s not in admitted]
+            pos_np = self._to_host(self.pos, "pos")
+            kv_lens = [int(min(pos_np[s] + 1, self.s_cache)) for s in live]
         self._inc("fused_rounds")
         try:
             if self.fault_plan is not None:
@@ -876,114 +935,97 @@ class Engine:
                     block=self.decode_block, impl=self.decode_impl,
                     bucket=self.prefill_bucket)
                 sp.attach(logits_dec)
-            if self.fault_plan is not None and \
-                    self.fault_plan.poisons_admit(a_rnd):
-                states = jax.tree.map(
-                    lambda x: jnp.full_like(x, jnp.nan)
-                    if jnp.issubdtype(x.dtype, jnp.floating) else x, states)
-            if self.guard_output and not D.states_finite(states):
-                raise F.PoisonedOutput(
-                    f"fused round {d_rnd}: non-finite packed states")
         except Exception as e:  # noqa: BLE001 — hardening boundary
-            # fused -> split: requeue the admits at the head (aging keeps
-            # them first) and re-run the round through the split ladders,
-            # which own retries / further degradation for this fault.
-            self._inc("fused_fallbacks")
-            self._degrade("step", d_rnd, "fused", "split",
-                          reason=f"{type(e).__name__}: {e}")
-            for req in reqs:
-                req.status = "queued"
-            self.queue[0:0] = reqs
-            self._admit()
-            self.step()
-            return
+            return self._fused_fallback(reqs, d_rnd, e)
         # -- commit (the exact split order: admit splice, then decode) ---
-        self._admit_round_idx += 1
-        self._decode_round_idx += 1
-        self._inc("admit_rounds")
-        self._admit_order_log.append(
-            [(r.uid, self._prefill_tiles(r)) for r in reqs])
-        self._admit_round_tiles.append(
-            sum(self._prefill_tiles(r) for r in reqs))
-        self._inc("fused_launches")
-        self._inc("fused_tiles", info["tiles"])
-        self.fused_templates.add(
-            (tuple(info["template"]), int(info["capacity"])))
-        self._inc("prefill_requests", len(pairs))
-        self._inc("prefill_tokens", sum(lens))
-        if live:
-            self._inc("decode_rounds")
-        self.cache = cache
-        for (slot, req), start, length in zip(pairs, starts, lens):
-            self._splice_slot(slot, states, start, length)
-            self.slot_req[slot] = req
-            self.remaining[slot] = req.max_new - len(req.out)
-        # decode-half poison guard, identical to step()
-        bad: List[int] = []
-        logits_np = np.array(logits_dec, np.float32)
-        if self.fault_plan is not None:
-            for s in self.fault_plan.poison_slots(d_rnd, live):
-                logits_np[s] = np.nan
-        if self.guard_output:
-            bad = D.poisoned_slots(logits_np, live)
-        if bad and self.escalate_step_errors:
-            # fleet replica: escalate instead of slot-quarantining. The
-            # fused cache/splice commits above are discarded with the
-            # replica — no token was appended for any slot this round, so
-            # every request's feed is still pre-fault and migration
-            # re-prefills the exact state.
-            raise F.PoisonedOutput(
-                f"fused round {d_rnd}: non-finite decode logits in "
-                f"slots {bad}")
-        replays: List[Request] = []
-        for slot in bad:
-            req = self.slot_req[slot]
-            self.slot_req[slot] = None
-            self.quarantined[slot] = d_rnd + 1 + self.quarantine_rounds
-            req.replays += 1
-            req.status = "queued"
-            replays.append(req)
-            self._inc_res("slots_quarantined_total")
-            if SK.trace_enabled():
-                SK.emit_event({"type": "quarantine", "slot": slot,
-                               "uid": req.uid, "round": d_rnd,
-                               "reason": "nonfinite_logits"})
-        if replays:
-            self.queue[0:0] = replays
+        # the states guard belongs to the fused attempt: whatever it
+        # raises takes the fused -> split rung, and nothing is committed
+        err = None
+        with TR.span("engine.admit_commit"):
+            try:
+                if self.fault_plan is not None and \
+                        self.fault_plan.poisons_admit(a_rnd):
+                    states = _poison(states)
+                if self.guard_output and not D.states_finite(
+                        states, self._to_host):
+                    raise F.PoisonedOutput(
+                        f"fused round {d_rnd}: non-finite packed states")
+            except Exception as e:  # noqa: BLE001 — hardening boundary
+                err = e
+            else:
+                self._admit_round_idx += 1
+                self._decode_round_idx += 1
+                self._log_admit(reqs)
+                self._inc("fused_launches")
+                self._inc("fused_tiles", info["tiles"])
+                self.fused_templates.add(
+                    (tuple(info["template"]), int(info["capacity"])))
+                self._inc("prefill_requests", len(pairs))
+                self._inc("prefill_tokens", sum(lens))
+                if live:
+                    self._inc("decode_rounds")
+                self.cache = cache
+                for (slot, req), start, length in zip(pairs, starts, lens):
+                    self._splice_slot(slot, states, start, length)
+                    self.slot_req[slot] = req
+                    self.remaining[slot] = req.max_new - len(req.out)
+        if err is not None:
+            return self._fused_fallback(reqs, d_rnd, err)
+        # decode-half guard, as in step(); the fused cache/splice commits
+        # above are discarded with the replica if it escalates — no token
+        # was appended this round, so every feed is still pre-fault.
+        with TR.span("engine.guard"):
+            bad = self._guard(logits_dec, d_rnd, live, "fused")
         # ONE key split per fused round (the admits' first tokens and the
         # decode tokens share it; at temperature=0 both are pure argmax).
-        self.key, k = jax.random.split(self.key)
-        nxt_np = np.asarray(D.sample_logits(
-            k, logits_dec, temperature=self.temperature,
-            vocab_size=self.cfg.vocab_size))
-        adm_np = np.asarray(D.sample_logits(
-            k, logits_admit, temperature=self.temperature,
-            vocab_size=self.cfg.vocab_size))
-        new_pos = np.asarray(self.pos).copy()
-        new_last = np.asarray(self.last_tok).copy()
-        for slot in live:
-            if slot in bad:
-                continue
-            req = self.slot_req[slot]
-            req.out.append(int(nxt_np[slot]))
-            new_pos[slot] += 1
-            new_last[slot, 0] = int(nxt_np[slot])
-            self.remaining[slot] -= 1
-            if self.remaining[slot] <= 0 or \
-                    int(new_pos[slot]) >= self.max_len - 1:
-                self._finish(req, "done")
-                self.slot_req[slot] = None
-        for (slot, req), length, tok in zip(pairs, lens, adm_np):
-            req.out.append(int(tok))
-            new_pos[slot] = length  # the sampled token's position
-            new_last[slot, 0] = int(tok)
-            self.remaining[slot] -= 1
-            if self.remaining[slot] <= 0 or \
-                    int(new_pos[slot]) >= self.max_len - 1:
-                self._finish(req, "done")
-                self.slot_req[slot] = None
-        self.pos = jnp.asarray(new_pos)
-        self.last_tok = jnp.asarray(new_last)
+        with TR.span("engine.sample"):
+            self.key, k = jax.random.split(self.key)
+            nxt_np = self._to_host(D.sample_logits(
+                k, logits_dec, temperature=self.temperature,
+                vocab_size=self.cfg.vocab_size), "tokens")
+            adm_np = self._to_host(D.sample_logits(
+                k, logits_admit, temperature=self.temperature,
+                vocab_size=self.cfg.vocab_size), "admit_tokens")
+        with TR.span("engine.commit"):
+            new_pos = self._to_host(self.pos, "pos").copy()
+            new_last = self._to_host(self.last_tok, "last_tok").copy()
+            for slot in live:
+                if slot in bad:
+                    continue
+                req = self.slot_req[slot]
+                req.out.append(int(nxt_np[slot]))
+                new_pos[slot] += 1
+                new_last[slot, 0] = int(nxt_np[slot])
+                self.remaining[slot] -= 1
+                if self.remaining[slot] <= 0 or \
+                        int(new_pos[slot]) >= self.max_len - 1:
+                    self._finish(req, "done")
+                    self.slot_req[slot] = None
+            for (slot, req), length, tok in zip(pairs, lens, adm_np):
+                req.out.append(int(tok))
+                new_pos[slot] = length  # the sampled token's position
+                new_last[slot, 0] = int(tok)
+                self.remaining[slot] -= 1
+                if self.remaining[slot] <= 0 or \
+                        int(new_pos[slot]) >= self.max_len - 1:
+                    self._finish(req, "done")
+                    self.slot_req[slot] = None
+            self.pos = jnp.asarray(new_pos)
+            self.last_tok = jnp.asarray(new_last)
+
+    def _fused_fallback(self, reqs: List[Request], d_rnd: int,
+                        err: BaseException):
+        """fused -> split: requeue the admits at the head (aging keeps
+        them first) and re-run the round through the split ladders, which
+        own retries / further degradation for this fault."""
+        self._inc("fused_fallbacks")
+        self._degrade("step", d_rnd, "fused", "split",
+                      reason=f"{type(err).__name__}: {err}")
+        for req in reqs:
+            req.status = "queued"
+        self.queue[0:0] = reqs
+        self._admit()
+        self.step()
 
     def idle(self) -> bool:
         """True iff the engine holds no work (empty queue, no live slot)."""
@@ -992,38 +1034,34 @@ class Engine:
     def round(self):
         """ONE full scheduling round — the unit a fleet driver advances a
         replica by: deadline sweep, then either a fused step or a split
-        admit + decode pair. run() is this in a drain loop; a Fleet calls
-        it directly so it can heartbeat/watch each replica per round."""
-        self._expire_deadlines()
-        if self.step_mode == "fused":
-            self._release_quarantine()
-            if not self.idle():
-                self.step_fused()
-            return
-        self._admit()
-        self.step()
+        admit + decode pair, under the spans of SPANS. run() is this in a
+        drain loop; a Fleet calls it directly so it can heartbeat/watch
+        each replica per round."""
+        with TR.span("engine.round"):
+            with TR.span("engine.schedule"):
+                self._expire_deadlines()
+                pairs = self._take_admits()
+                plan = None if self.step_mode == "fused" \
+                    else self._plan_admit(pairs)
+            if self.step_mode == "fused" and pairs:
+                self._step_fused(pairs)
+                return
+            self._run_admit(plan)
+            self.step()
 
     def run(self, max_steps: int = 10_000) -> Dict[int, List[int]]:
-        """Drive admission + decode until drained (or max_steps rounds).
+        """Drive rounds until drained (or max_steps rounds).
 
         step_mode="fused" folds each round's admission INTO its decode
-        launch (step_fused); "split" keeps the separate packed-admit and
+        launch (_step_fused); "split" keeps the separate packed-admit and
         decode rounds. Returns {uid: tokens} for every request that
         reached a terminal state — including the partial outputs of shed /
         deadline-missed / failed requests (see report() for statuses).
         Per-step failures never abort unaffected slots."""
         for _ in range(max_steps):
-            self._expire_deadlines()
-            if self.step_mode == "fused":
-                self._release_quarantine()
-                if all(r is None for r in self.slot_req) and not self.queue:
-                    break
-                self.step_fused()
-                continue
-            self._admit()
-            if all(r is None for r in self.slot_req) and not self.queue:
+            if self.idle():
                 break
-            self.step()
+            self.round()
         return {r.uid: r.out for r in self.finished}
 
     # -- crash safety --------------------------------------------------------

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 
 from repro.models import model as MD
 from repro.obs import metrics as MET
-from repro.obs import trace as TR
 from repro.train import optimizer as OPT
 
 
@@ -69,15 +68,7 @@ def make_train_step(cfg, opt: OPT.OptConfig, *, microbatches: int = 1,
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
-    step_labels = {"impl": attn_impl,
-                   "packed": "1" if packed is not None else "0"}
-
     def train_step(state: TrainState, batch):
-        # Host-side telemetry: fires per eager call, or once per trace when
-        # the caller jits the step (the same trace-time convention as the
-        # kernel launch counters — see obs/launch.py).
-        MET.counter_inc("train_step_calls", 1, step_labels)
-        MET.counter_inc("train_microbatches", microbatches, step_labels)
         params = state.params
 
         if microbatches == 1:
@@ -117,15 +108,7 @@ def make_train_step(cfg, opt: OPT.OptConfig, *, microbatches: int = 1,
                                step=state.step + 1, err_state=err)
         return new_state, metrics
 
-    def instrumented_step(state: TrainState, batch):
-        # Wall-clock covers device work for eager callers (attach ->
-        # block_until_ready); under jit the span covers the trace only.
-        with TR.span("train.step", **step_labels) as sp:
-            new_state, metrics = train_step(state, batch)
-            sp.attach(metrics)
-        return new_state, metrics
-
-    return instrumented_step
+    return train_step
 
 
 # ---------------------------------------------------------------------------
