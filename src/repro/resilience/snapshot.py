@@ -107,7 +107,7 @@ def snapshot(engine) -> EngineSnapshot:
         params=_host(engine.params),
         cache=_host(engine.cache),
         init_kw=dict(engine._init_kw),
-        pos=np.asarray(engine.pos),
+        pos=np.array(engine.pos, np.int32),
         last_tok=np.asarray(engine.last_tok),
         remaining=np.asarray(engine.remaining).copy(),
         key_data=np.asarray(jax.random.key_data(engine.key)),
@@ -150,7 +150,7 @@ def restore(snap: EngineSnapshot, *, params=None, fault_plan=None,
     eng.fused_templates = {(tuple(tpl), int(cap))
                            for tpl, cap in snap.fused_templates}
     eng.cache = jax.tree.map(jnp.asarray, snap.cache)
-    eng.pos = jnp.asarray(snap.pos)
+    eng.pos = np.array(snap.pos, np.int32)
     eng.last_tok = jnp.asarray(snap.last_tok)
     eng.remaining = np.asarray(snap.remaining).copy()
     eng.key = jax.random.wrap_key_data(jnp.asarray(snap.key_data))

@@ -184,7 +184,10 @@ class Engine:
         prefill_impl = prefill_impl or attn_ops.platform_impl()
         decode_impl = decode_impl or attn_ops.platform_impl()
         self.cache = MD.init_cache(cfg, slots, max_len, cache_dtype)
-        self.pos = jnp.zeros((slots,), jnp.int32)
+        # slot positions live on the host: every writer below rebinds a new
+        # array (a launch or a snapshot may still hold the old one), and
+        # every launch takes it as an argument, so no round reads it back
+        self.pos = np.zeros((slots,), np.int32)
         self.last_tok = jnp.zeros((slots, 1), jnp.int32)
         self.slot_req: List[Optional[Request]] = [None] * slots
         self.remaining = np.zeros((slots,), np.int64)
@@ -315,6 +318,13 @@ class Engine:
         metrics.json carries."""
         self.registry.counter_inc(name, value)
         MET.counter_inc(name, value)
+
+    def _set_pos(self, slot: int, value: int):
+        """Rebind ``pos`` to a copy with ``slot`` set: the array a launch or
+        a snapshot was handed is never written in place."""
+        pos = self.pos.copy()
+        pos[slot] = value
+        self.pos = pos
 
     def _to_host(self, x, what: str, read=np.asarray):
         """Every blocking device-to-host read of a round goes through here,
@@ -514,13 +524,12 @@ class Engine:
         toks = req.feed
         for t_idx, tok in enumerate(toks):
             tok_b = self.last_tok.at[slot, 0].set(int(tok))
-            pos_b = self.pos.at[slot].set(t_idx)
+            self._set_pos(slot, t_idx)
             logits, cache = self._decode(self.params, self.cache, tok_b,
-                                         pos_b)
+                                         self.pos)
             self.cache = jax.tree.map(merge, cache, self.cache)
             self.last_tok = tok_b
-            self.pos = pos_b
-        self.pos = self.pos.at[slot].set(len(toks) - 1)
+        self._set_pos(slot, len(toks) - 1)
         self.slot_req[slot] = req
         self.remaining[slot] = req.max_new - len(req.out)
         self._inc("prefill_launches", len(toks))
@@ -583,7 +592,7 @@ class Engine:
                 self._splice_slot(slot, states, start, length)
                 self.last_tok = self.last_tok.at[slot, 0].set(
                     int(req.feed[-1]))
-                self.pos = self.pos.at[slot].set(length - 1)
+                self._set_pos(slot, length - 1)
                 self.slot_req[slot] = req
                 self.remaining[slot] = req.max_new - len(req.out)
 
@@ -766,8 +775,7 @@ class Engine:
             if not active.any():
                 return
             live = [s for s in range(self.B) if active[s]]
-            pos_np = self._to_host(self.pos, "pos")
-            kv_lens = [int(min(pos_np[s] + 1, self.s_cache)) for s in live]
+            kv_lens = [int(min(self.pos[s] + 1, self.s_cache)) for s in live]
             # round geometry (recorded every round, whichever path runs):
             # what the packed grid covers vs what pad-to-max lockstep would.
             tiles = [-(-kl // self.decode_block) for kl in kv_lens]
@@ -832,7 +840,7 @@ class Engine:
             adv = active.copy()
             for slot in bad:
                 adv[slot] = False  # quarantined: state reset at readmission
-            self.pos = self.pos + jnp.asarray(adv, jnp.int32)
+            self.pos = self.pos + adv.astype(np.int32)
             self.last_tok = nxt[:, None]
             for slot in range(self.B):
                 req = self.slot_req[slot]
@@ -840,9 +848,8 @@ class Engine:
                     continue
                 req.out.append(int(nxt_np[slot]))
                 self.remaining[slot] -= 1
-                if self.remaining[slot] <= 0 or self._to_host(
-                        self.pos[slot], "pos_slot", int) \
-                        >= self.max_len - 1:
+                if self.remaining[slot] <= 0 or \
+                        int(self.pos[slot]) >= self.max_len - 1:
                     self._finish(req, "done")
                     self.slot_req[slot] = None  # refilled next admit
 
@@ -911,8 +918,7 @@ class Engine:
             admitted = [sl for sl, _ in pairs]
             live = [s for s in range(self.B) if self.slot_req[s] is not None
                     and s not in admitted]
-            pos_np = self._to_host(self.pos, "pos")
-            kv_lens = [int(min(pos_np[s] + 1, self.s_cache)) for s in live]
+            kv_lens = [int(min(self.pos[s] + 1, self.s_cache)) for s in live]
         self._inc("fused_rounds")
         try:
             if self.fault_plan is not None:
@@ -987,7 +993,7 @@ class Engine:
                 k, logits_admit, temperature=self.temperature,
                 vocab_size=self.cfg.vocab_size), "admit_tokens")
         with TR.span("engine.commit"):
-            new_pos = self._to_host(self.pos, "pos").copy()
+            new_pos = self.pos.copy()
             new_last = self._to_host(self.last_tok, "last_tok").copy()
             for slot in live:
                 if slot in bad:
@@ -1010,7 +1016,7 @@ class Engine:
                         int(new_pos[slot]) >= self.max_len - 1:
                     self._finish(req, "done")
                     self.slot_req[slot] = None
-            self.pos = jnp.asarray(new_pos)
+            self.pos = new_pos
             self.last_tok = jnp.asarray(new_last)
 
     def _fused_fallback(self, reqs: List[Request], d_rnd: int,

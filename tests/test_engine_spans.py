@@ -32,8 +32,7 @@ ROUND_ORDERS = {
                "engine.admit_commit", "engine.guard", "engine.sample",
                "engine.commit"], DECODE],
 }
-READS = {"pos", "pos_slot", "logits", "tokens", "admit_tokens", "last_tok",
-         "states_finite"}
+READS = {"logits", "tokens", "admit_tokens", "last_tok", "states_finite"}
 PROMPTS = [np.array([3, 1, 4, 1, 5], np.int32),
            np.array([2, 7, 1], np.int32),
            np.array([9, 9, 8, 2, 6, 5], np.int32)]
@@ -45,27 +44,34 @@ def model():
     return cfg, MD.init_params(jax.random.key(0), cfg)
 
 
-def _serve(model, mode):
+def _serve(model, mode, slots=2, prompts=PROMPTS):
     cfg, params = model
-    eng = E.Engine(params, cfg, slots=2, max_len=48, temperature=0.0,
+    eng = E.Engine(params, cfg, slots=slots, max_len=48, temperature=0.0,
                    step_mode=mode)
-    for uid, p in enumerate(PROMPTS):
+    for uid, p in enumerate(prompts):
         eng.submit(p, max_new=6, uid=uid)
     return eng.run()
+
+
+def _traced_run(model, mode, trace_dir, **kw):
+    """(tokens, span events) of a run with the trace sink on."""
+    path = SK.enable(trace_dir=str(trace_dir), metrics_path=None,
+                     run_id="spans")
+    try:
+        out = _serve(model, mode, **kw)
+    finally:
+        SK.disable()
+    events = [json.loads(line) for line in
+              pathlib.Path(path).read_text().splitlines()]
+    return out, [ev for ev in events if ev["type"] == "span"]
 
 
 @pytest.fixture(scope="module", params=["split", "fused"])
 def traced(request, model, tmp_path_factory):
     """(mode, tokens, span events) of a run with the trace sink on."""
-    path = SK.enable(trace_dir=str(tmp_path_factory.mktemp("trace")),
-                     metrics_path=None, run_id="spans")
-    try:
-        out = _serve(model, request.param)
-    finally:
-        SK.disable()
-    events = [json.loads(line) for line in
-              pathlib.Path(path).read_text().splitlines()]
-    return request.param, out, [ev for ev in events if ev["type"] == "span"]
+    out, spans = _traced_run(model, request.param,
+                             tmp_path_factory.mktemp("trace"))
+    return request.param, out, spans
 
 
 def _rounds(spans):
@@ -111,16 +117,25 @@ def test_host_reads_are_spans_inside_a_phase(traced):
             assert ev["attrs"]["what"] in READS, ev
 
 
-def test_a_decode_round_reads_pos_logits_tokens_and_each_slot(traced):
-    """The first decode-only round: both slots live with tokens to go, so
-    3 + slots blocking reads."""
-    _, _, spans = traced
-    rnd, inside = next((r, i) for r, i in _rounds(spans)
-                       if [e["name"] for e in i
-                           if e["parent"] == "engine.round"] == DECODE)
+@pytest.mark.parametrize("slots", [2, 4])
+@pytest.mark.parametrize("mode", ["split", "fused"])
+def test_a_decode_round_reads_pos_logits_tokens_and_each_slot(
+        model, mode, slots, tmp_path):
+    """The first decode-only round with every slot live and tokens to go
+    makes two blocking reads, the logits and the tokens, whatever the
+    slot count: the host owns the slot positions, so neither ``pos`` nor
+    any slot's position is read back."""
+    prompts = (PROMPTS * 2)[:slots]
+    _, spans = _traced_run(model, mode, tmp_path, slots=slots,
+                           prompts=prompts)
+    inside = next(i for _, i in _rounds(spans)
+                  if [e["name"] for e in i
+                      if e["parent"] == "engine.round"] == DECODE
+                  and next(e for e in i if e["name"] ==
+                           "engine.decode_round")["attrs"]["live"] == slots)
     reads = [ev["attrs"]["what"] for ev in inside
              if ev["name"] == "engine.to_host"]
-    assert reads == ["pos", "logits", "tokens", "pos_slot", "pos_slot"]
+    assert reads == ["logits", "tokens"]
 
 
 def test_span_events_validate(traced):
@@ -161,7 +176,8 @@ def test_device_values_reach_the_host_only_in_to_host(model, mode,
     out = _serve(model, mode)
     monkeypatch.undo()
     assert set(out) == {0, 1, 2}
-    assert {n for n, _ in seen} >= {"__int__", "__bool__"}
+    # the scalar reads left in a round are the states guard's
+    assert {n for n, _ in seen} == {"__bool__"}
     assert {span for _, span in seen} == {"engine.to_host"}
 
 

@@ -4,6 +4,7 @@ isolated sequential decode, cache accounting."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import registry as REG
 from repro.models import model as MD
@@ -51,6 +52,57 @@ def test_engine_matches_sequential_decode():
     for uid, p in enumerate(prompts):
         ref = _greedy_reference(params, cfg, list(p), 6, 48)
         assert results[uid] == ref, (uid, results[uid], ref)
+
+
+@pytest.mark.parametrize("step_mode,prefill_mode", [
+    ("split", "packed"), ("fused", "packed"), ("split", "sequential")])
+def test_engine_slot_positions_live_on_the_host(step_mode, prefill_mode):
+    """``Engine.pos`` is a host int32 vector that every round keeps at
+    prompt length + tokens emitted - 1 for each live slot; requests end by
+    ``max_new`` or at ``max_len - 1``, greedy tokens match the sequential
+    reference, and a mid-run snapshot holds a copy that restores to the
+    same tokens."""
+    cfg, params = _setup()
+    max_len = 16
+    prompts = [np.array([3, 1, 4, 1, 5], np.int32),
+               np.array([2, 7, 1], np.int32),
+               np.arange(1, 11, dtype=np.int32)]
+    max_new = [4, 5, 20]  # the last stops at max_len - 1, after 6 tokens
+
+    def make():
+        eng = Engine(params, cfg, slots=2, max_len=max_len,
+                     temperature=0.0, step_mode=step_mode,
+                     prefill_mode=prefill_mode)
+        for uid, (p, n) in enumerate(zip(prompts, max_new)):
+            eng.submit(p, max_new=n, uid=uid)
+        return eng
+
+    eng, snap, rounds = make(), None, 0
+    while not eng.idle():
+        eng.round()
+        rounds += 1
+        assert type(eng.pos) is np.ndarray
+        assert eng.pos.dtype == np.int32 and eng.pos.shape == (2,)
+        for slot, req in enumerate(eng.slot_req):
+            if req is not None:
+                assert eng.pos[slot] == len(req.prompt) + len(req.out) - 1
+        if rounds == 2:
+            snap = eng.snapshot()
+            pos_at_snap = eng.pos.copy()
+    assert snap is not None and rounds > 3
+    # the engine moved on; the snapshot's positions did not move with it
+    np.testing.assert_array_equal(snap.pos, pos_at_snap)
+    results = {r.uid: r.out for r in eng.finished}
+    assert [len(results[u]) for u in range(3)] == [4, 5, max_len - 10]
+    for uid, p in enumerate(prompts):
+        ref = _greedy_reference(params, cfg, list(p), len(results[uid]),
+                                max_len)
+        assert results[uid] == ref, (uid, results[uid], ref)
+    restored = Engine.restore(snap)
+    assert type(restored.pos) is np.ndarray
+    assert restored.pos.dtype == np.int32
+    np.testing.assert_array_equal(restored.pos, pos_at_snap)
+    assert restored.run() == results
 
 
 def test_engine_packed_prefill_matches_sequential():
