@@ -19,6 +19,7 @@ import numpy as np
 
 from bench.harness import generate as G
 from bench.harness import reference as REF
+from bench.harness import spec as SP
 
 
 def sample_served(finished, seed: int, n: int) -> list:
@@ -35,8 +36,8 @@ def sample_served(finished, seed: int, n: int) -> list:
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def served_gap(config: dict, traffic: dict, seed: int, finished
-               ) -> Tuple[float, dict]:
+def served_gap(config: dict, traffic: dict, seed: int, finished, *,
+               base=SP.BENCH_DIR) -> Tuple[float, dict]:
     """(widest gap over the sample, details). A token outside the
     vocabulary reads as an infinite gap."""
     chk = traffic["check"]
@@ -49,7 +50,7 @@ def served_gap(config: dict, traffic: dict, seed: int, finished
     if any(((o < 0) | (o >= vocab)).any() for _, o in pairs):
         return float("inf"), {"requests": len(pairs), "tokens": 0,
                               "out_of_vocab": True}
-    model = REF.Model.from_config(config)
+    model = SP.load_family(config, base).Reference.from_config(config)
     gaps = REF.served_gaps(model, seed, [(p, o.astype(np.int32))
                                          for p, o in pairs],
                            pad_to=chk["pad_to"], batch=chk["requests"])
@@ -61,13 +62,13 @@ def served_gap(config: dict, traffic: dict, seed: int, finished
         "longest": int(max(len(p) + len(o) for p, o in pairs))}
 
 
-def control_gap(config: dict, traffic: dict, seed: int, finished
-                ) -> Tuple[float, dict]:
+def control_gap(config: dict, traffic: dict, seed: int, finished, *,
+                base=SP.BENCH_DIR) -> Tuple[float, dict]:
     """The control on the same sample: the token the fp8 reference puts
     first at each position, scored like a served token."""
     chk = traffic["check"]
     sample = sample_served(finished, seed, chk["requests"])
-    model = REF.Model.from_config(config)
+    model = SP.load_family(config, base).Reference.from_config(config)
     gaps = REF.control_gaps(model, seed, [(s.item.prompt,
                                            np.asarray(s.req.out, np.int32))
                                           for s in sample],

@@ -2,10 +2,14 @@
 
 Each reader takes the run record that ``bench/run.py`` assembles:
 
-  rec["shape"]          work.Shape of the configuration
+  rec["shape"]          the ``Shape`` of the configuration's family
+                        (work.Shape for the dense one)
   rec["peak"]           the device's row of bench/peaks.json
-  rec["trace"]          trace_reduce.reduce() of the traced window, or None
-  rec["rounds"]         serving rounds in the window (serving.Round)
+  rec["trace"]          trace_reduce.reduce() of the traced window, or None;
+                        its ``marks`` are the traced rounds (serving) or
+                        windows (training)
+  rec["rounds"]         serving rounds in the window (serving.Round, with
+                        the change of each engine counter over the round)
   rec["traced_rounds"]  the rounds run while the profiler traced
   rec["traced_steps"]   training: doc lengths of each step in the trace
   rec["compiles"]       programs built inside the window
@@ -42,6 +46,34 @@ def idle_share(rec) -> Optional[float]:
     if not red or red["busy_ns"] <= 0:
         return None
     return 100.0 * (1.0 - red["busy_ns"] / red["window_ns"])
+
+
+def _engine_trace(rec) -> Optional[dict]:
+    """The reduced trace, where the engine's round span is in it."""
+    red = rec.get("trace")
+    if not red or not red["span_count"].get("engine.round") or \
+            not red.get("marks"):
+        return None
+    return red
+
+
+def engine_host_ms_per_round(rec) -> Optional[float]:
+    """Device idle under the engine's phase spans, per traced round, ms."""
+    red = _engine_trace(rec)
+    if red is None:
+        return None
+    idle = sum(ns for label, ns in red["idle_by_label"].items()
+               if label.startswith("engine."))
+    return idle / red["marks"] / 1e6
+
+
+def host_syncs_per_round(rec) -> Optional[float]:
+    """Blocking device-to-host reads (``engine.to_host`` spans) per traced
+    round."""
+    red = _engine_trace(rec)
+    if red is None:
+        return None
+    return red["span_count"].get("engine.to_host", 0) / red["marks"]
 
 
 def _span_s(rec, name: str) -> float:

@@ -4,10 +4,23 @@ Written from the layer equations of the configurations' papers (Yi,
 arXiv 2403.04652; Granite code models, arXiv 2405.04324) as this
 benchmark's configuration files state them, and independent of the
 program: it imports nothing of ``repro`` and takes no array the program
-made. Weights are made again, layer by layer, from the seed
+made. Weights are made again, block by block, from the seed
 (``weights.layer_weights``), with the values they are served in.
 
-Each layer is pre-norm residual:
+The functions below (``run``, ``served_gaps``, ``control_gaps``,
+``params``, ``logits``, ``flat_params``, ``train_loss``,
+``train_reference``) take a family's model object (bench/families/, its
+``Reference``): a frozen, hashable dataclass with ``d``, ``vocab``,
+``eps``, ``dtype`` (what the matrices are served in), ``blocks`` (the
+length of the program's stacked layer axis: one block is one scan step,
+so ``layers/l{p}/...`` for each layer p of the pattern),
+``block_shapes()`` and ``served_dtypes()`` (one block's leaf paths, their
+shapes and served dtypes), ``block(wts, x, prec)`` over (B, S, d) at
+positions 0..S-1 and, for training, ``train_block(wts, x, prec, docs)``
+over the real tokens of packed documents. The embedding, the final norm
+and the head are these functions' own. ``Model`` is the dense family's.
+
+Each dense layer is pre-norm residual:
 
   h = RMSNorm(x) * g1;  q, k, v = h Wq, h Wk, h Wv;  RoPE (rotate-half)
   on q and k;  o = causal softmax(q k^T / sqrt(hd)) v, query head i
@@ -76,7 +89,17 @@ class Model:
                    act=c["mlp"], rope_theta=float(c["rope_theta"]),
                    eps=float(c["rms_norm_eps"]), dtype=c["torch_dtype"])
 
-    def layer_shapes(self) -> Dict[str, tuple]:
+    @property
+    def blocks(self) -> int:
+        return self.layers
+
+    def block(self, wts, x, prec: str):
+        return _layer(wts, x, self, prec)
+
+    def train_block(self, wts, x, prec: str, docs: Tuple[int, ...]):
+        return _train_layer(wts, x, self, prec, docs)
+
+    def block_shapes(self) -> Dict[str, tuple]:
         d, hd, f = self.d, self.head_dim, self.ff
         s = {LAYER["attn_norm"]: (d,), LAYER["mlp_norm"]: (d,),
              LAYER["wq"]: (d, self.heads * hd),
@@ -91,7 +114,7 @@ class Model:
     def served_dtypes(self) -> Dict[str, object]:
         mat = jnp.dtype(self.dtype)
         return {p: (jnp.float32 if len(s) == 1 else mat)
-                for p, s in self.layer_shapes().items()}
+                for p, s in self.block_shapes().items()}
 
 
 def _mm32(a, b):
@@ -203,20 +226,20 @@ def _mlp(wts, x, m: Model, prec: str):
 
 
 @functools.partial(jax.jit, static_argnames=("m", "prec"))
-def _layer_step(key, layer, x, m: Model, prec: str):
-    wts = W.layer_weights(key, layer, m.layer_shapes(), m.served_dtypes())
-    return _layer(wts, x, m, prec)
+def _block_step(key, block, x, m, prec: str):
+    wts = W.layer_weights(key, block, m.block_shapes(), m.served_dtypes())
+    return m.block(wts, x, prec)
 
 
 @functools.partial(jax.jit, static_argnames=("m",))
-def _embed(key, tokens, m: Model):
+def _embed(key, tokens, m):
     table = W.leaf_values(key, TOP["embed"], (m.vocab, m.d))
     return jnp.take(table.astype(m.dtype).astype(jnp.float32), tokens,
                     axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "prec"))
-def _head(key, x_rows, score, m: Model, prec: str):
+def _head(key, x_rows, score, m, prec: str):
     """x_rows: (R, d) final hidden rows; score: (K, R) tokens to score.
     Returns (argmax (R,), gaps (K, R)): each scored token's logit below
     the row's best."""
@@ -230,7 +253,7 @@ def _head(key, x_rows, score, m: Model, prec: str):
         ..., 0]
 
 
-def run(m: Model, seed: int, seqs: Sequence[np.ndarray],
+def run(m, seed: int, seqs: Sequence[np.ndarray],
         rows: Sequence[np.ndarray], prec: str = "f32",
         score: Optional[Sequence[Sequence[np.ndarray]]] = None,
         pad_to: int = 0, batch: int = 0) -> dict:
@@ -252,8 +275,8 @@ def run(m: Model, seed: int, seqs: Sequence[np.ndarray],
     for i, s in enumerate(seqs):
         tokens[i, :len(s)] = s
     x = _embed(key, jnp.asarray(tokens), m)
-    for layer in range(m.layers):
-        x = _layer_step(key, jnp.int32(layer), x, m, prec)
+    for block in range(m.blocks):
+        x = _block_step(key, jnp.int32(block), x, m, prec)
     n_rows = max(len(r) for r in rows)
     flat = np.zeros((b, n_rows), np.int32)
     sc = np.zeros((len(score), b, n_rows), np.int32)
@@ -273,7 +296,7 @@ def run(m: Model, seed: int, seqs: Sequence[np.ndarray],
                      for j in range(len(score))]}
 
 
-def served_gaps(m: Model, seed: int, served: List[tuple], *,
+def served_gaps(m, seed: int, served: List[tuple], *,
                 pad_to: int = 0, batch: int = 0, prec: str = "f32"
                 ) -> List[np.ndarray]:
     """For each (prompt, served tokens): how far each served token's
@@ -291,7 +314,7 @@ def served_gaps(m: Model, seed: int, served: List[tuple], *,
     return res["gaps"][0]
 
 
-def control_gaps(m: Model, seed: int, served: List[tuple], *,
+def control_gaps(m, seed: int, served: List[tuple], *,
                  pad_to: int = 0, batch: int = 0) -> List[np.ndarray]:
     """The control: at each position of the same sequences, the token that
     the fp8 reference puts first, scored by the float32 reference."""
@@ -310,9 +333,9 @@ def control_gaps(m: Model, seed: int, served: List[tuple], *,
 # -- whole-model form (small models, training): explicit float32 params ------
 
 
-def params(m: Model, seed: int) -> dict:
+def params(m, seed: int) -> dict:
     """Every weight as float32 arrays of the values served:
-    {"embed", "final_norm", "head", "layers": [one dict per layer]}."""
+    {"embed", "final_norm", "head", "layers": [one dict per block]}."""
     key = W.base_key(seed)
     mat = jnp.dtype(m.dtype)
     top = {"embed": W.leaf_values(key, TOP["embed"], (m.vocab, m.d)
@@ -320,23 +343,23 @@ def params(m: Model, seed: int) -> dict:
            "final_norm": W.leaf_values(key, TOP["final_norm"], (m.d,)),
            "head": W.leaf_values(key, TOP["head"], (m.d, m.vocab)
                                  ).astype(mat).astype(jnp.float32)}
-    top["layers"] = [W.layer_weights(key, i, m.layer_shapes(),
+    top["layers"] = [W.layer_weights(key, i, m.block_shapes(),
                                      m.served_dtypes())
-                     for i in range(m.layers)]
+                     for i in range(m.blocks)]
     return top
 
 
-def logits(p: dict, m: Model, tokens, prec: str = "f32"):
+def logits(p: dict, m, tokens, prec: str = "f32"):
     """(B, S) tokens -> (B, S, V) float32 logits."""
     x = jnp.take(p["embed"], tokens, axis=0)
     for wts in p["layers"]:
-        x = _layer(wts, x, m, prec)
+        x = m.block(wts, x, prec)
     return _mm(_rms(x, p["final_norm"], m.eps), p["head"], prec)
 
 
 # -- training: the first steps of AdamW over packed documents -----------------
 #
-# Parameters are keyed "<path>@<layer>" (a layer leaf, one layer's slice) or
+# Parameters are keyed "<path>@<block>" (a layer leaf, one block's slice) or
 # "<path>" (embedding, final norm, head), the keys the program's per-leaf
 # norms use. Attention runs one document at a time over its real tokens
 # (padding only ever sits at a document's causal tail, so leaving it out
@@ -346,7 +369,7 @@ def logits(p: dict, m: Model, tokens, prec: str = "f32"):
 CE_ROWS = 1024
 
 
-def flat_params(m: Model, seed: int) -> Dict[str, jax.Array]:
+def flat_params(m, seed: int) -> Dict[str, jax.Array]:
     p = params(m, seed)
     flat = {"embed": p["embed"], "final_norm": p["final_norm"],
             TOP["head"]: p["head"]}
@@ -395,15 +418,15 @@ def _train_layer(wts, x, m: Model, prec: str, docs: Tuple[int, ...]):
     return x + _mlp(wts, x, m, prec)
 
 
-def train_loss(flat: Dict[str, jax.Array], tokens, labels, m: Model,
+def train_loss(flat: Dict[str, jax.Array], tokens, labels, m,
                prec: str, docs: Tuple[int, ...]):
     """Mean next-token cross-entropy over the real tokens of one packed
     row: ``tokens``/``labels`` (T,) are the documents' real tokens end to
     end, T = sum(docs)."""
     x = jnp.take(flat["embed"], tokens, axis=0)
-    for i in range(m.layers):
-        wts = {path: flat[f"{path}@{i}"] for path in m.layer_shapes()}
-        x = jax.checkpoint(lambda w, y: _train_layer(w, y, m, prec, docs))(
+    for i in range(m.blocks):
+        wts = {path: flat[f"{path}@{i}"] for path in m.block_shapes()}
+        x = jax.checkpoint(lambda w, y: m.train_block(w, y, prec, docs))(
             wts, x)
     x = _rms(x, flat["final_norm"], m.eps)
     t = x.shape[0]
@@ -435,7 +458,7 @@ def decays(key: str) -> bool:
     return not (name.startswith("norm") or name == "final_norm")
 
 
-def train_reference(m: Model, seed: int, batches: Sequence[dict], opt: dict,
+def train_reference(m, seed: int, batches: Sequence[dict], opt: dict,
                     prec: str = "f32") -> dict:
     """AdamW over ``batches`` (each {"tokens", "labels", "docs"}) from the
     seeded weights, as the configuration states it, in float32.

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench.harness import generate as G
+from bench.harness import spec as SP
 from bench.harness import weights as WT
 
 
@@ -52,21 +53,21 @@ class Round:
     admitted: List[int]                 # prompt lengths prefilled
     kv_lens: List[int]                  # valid KV of each decoded slot
     packed: bool                        # decode took the packed path
+    # the change of each counter of the engine's registry over the round,
+    # where it changed (names as ``Registry.snapshot`` gives them)
+    counters: Dict[str, float]
 
 
 def model_config(c: dict):
     """The program's configuration object for a configuration file."""
-    from repro.configs.base import ModelConfig
+    return SP.load_family(c).program_config(c)
 
-    return ModelConfig(
-        name=c["name"], family="dense", n_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"], head_dim=c["head_dim"],
-        mlp_activation=c["mlp"], rope_theta=float(c["rope_theta"]),
-        norm_eps=float(c["rms_norm_eps"]), dtype=c["torch_dtype"],
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        layer_pattern=("attn",))
+
+def counter_change(before: dict, after: dict) -> Dict[str, float]:
+    """Counters that moved between two ``Registry.snapshot()["counters"]``
+    readings, by how much."""
+    return {k: v - before.get(k, 0) for k, v in after.items()
+            if v != before.get(k, 0)}
 
 
 def make_params(cfg, seed: int):
@@ -99,11 +100,12 @@ class ServeCell:
     """One serving run: ``setup()``, ``window()``, then ``served()``."""
 
     def __init__(self, config: dict, traffic: dict, seed: int,
-                 seconds: float, *, tracer=None):
+                 seconds: float, *, tracer=None, base=SP.BENCH_DIR):
         self.config, self.traffic = config, traffic
         self.seed, self.seconds = seed, seconds
         self.tracer = tracer            # (start, stop) callables or None
-        self.cfg = model_config(config)
+        self.family = SP.load_family(config, base)
+        self.cfg = self.family.program_config(config)
         self.items = G.serve_items(traffic, seed, seconds,
                                    config["vocab_size"])
         self.served: Dict[int, Served] = {}
@@ -201,12 +203,13 @@ class ServeCell:
     def _round(self, live: List[Served]):
         """One engine round; stamps the tokens it emitted."""
         eng = self.engine
-        before = eng.registry.counter_value("decode_packed_launches")
+        before = eng.registry.snapshot()["counters"]
         counts = [len(s.req.out) for s in live]
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench.round"):
             eng.round()
         t1 = time.perf_counter()
+        counters = counter_change(before, eng.registry.snapshot()["counters"])
         admitted, kv = [], []
         for s, k in zip(live, counts):
             new = len(s.req.out) - k
@@ -215,9 +218,9 @@ class ServeCell:
                 kv.append(len(s.item.prompt) + k)
                 if k == 0:
                     admitted.append(len(s.item.prompt))
-        self.rounds.append(Round(t0, t1, admitted, kv, eng.registry.
-                                 counter_value("decode_packed_launches")
-                                 > before))
+        self.rounds.append(Round(
+            t0, t1, admitted, kv,
+            counters.get("decode_packed_launches", 0) > 0, counters))
         return t1
 
     def _submit(self, s: Served):
@@ -345,10 +348,10 @@ def serve_cell(cell, config, traffic, limits, seed, seconds, trace,
     """One run of a serving cell: the run record for bench/run.py."""
     from bench.harness import checks as CK
     from bench.harness import device as DV
-    from bench.harness import work as WK
 
     sc = ServeCell(config, traffic, seed, seconds,
-                   tracer=(tracer.start, tracer.stop) if tracer else None)
+                   tracer=(tracer.start, tracer.stop) if tracer else None,
+                   base=base)
     sc.setup()
     t_start, t_end = sc.window()
     setup_s = t_start - t_process
@@ -388,7 +391,7 @@ def serve_cell(cell, config, traffic, limits, seed, seconds, trace,
         f"{sc.compiles.count} programs built in the window; set-up "
         f"{setup_s:.3f} s" + (f" (closed-loop warm-up {sc.warmup_s:.3f} s)"
                               if traffic["loop"] == "closed" else ""))
-    rec = {"shape": WK.Shape.from_config(config), "rounds": rounds,
+    rec = {"shape": sc.family.Shape.from_config(config), "rounds": rounds,
            "compiles": sc.compiles.count, "trace": None}
     if tracer is not None:
         rec["traced_rounds"] = [r for r in rounds
@@ -396,7 +399,7 @@ def serve_cell(cell, config, traffic, limits, seed, seconds, trace,
         rec["trace"] = tracer.reduce("bench.round")
     finished = sc.finished()
     sc.release()
-    gap, detail = CK.served_gap(config, traffic, seed, finished)
+    gap, detail = CK.served_gap(config, traffic, seed, finished, base=base)
     log(f"reference: {detail}")
     return {"values": values, "rec": rec, "attempted": attempted,
             "failed": failed, "device": dev,

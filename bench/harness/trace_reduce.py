@@ -3,8 +3,9 @@
 ``load`` reads the ``.xplane.pb`` that ``jax.profiler`` writes into two
 lists on one clock (nanoseconds): the device's operations (the "XLA Ops"
 line of each ``/device:TPU:n`` plane) and the host spans that the
-program and the harness open with ``TraceAnnotation`` (names in
-``SPANS``).
+program and the harness open with ``TraceAnnotation``: every span whose
+name starts with one of ``PREFIXES``, so a span the engine adds is read
+with no edit here, and the names in ``SPANS``.
 
 The device's timestamps run about a millisecond behind the host's on a
 v5e. ``load`` moves them onto the host clock by the smallest shift that
@@ -40,9 +41,11 @@ import pathlib
 import re
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
-# host spans that label the device's time, innermost first when nested
+# host spans that label the device's time: those the metrics always read,
+# innermost first when nested, and every span under one of the prefixes
 SPANS = ("engine.admit_batch", "engine.decode_round", "engine.fused_step",
          "bench.round", "bench.window")
+PREFIXES = ("engine.", "bench.")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 LAUNCH = "TpuLoadedExecutable::ExecuteLaunch"
@@ -120,7 +123,7 @@ def load(path) -> Trace:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name in SPANS:
+                    if ev.name in SPANS or ev.name.startswith(PREFIXES):
                         spans.append((ev.name, int(ev.start_ns),
                                       int(ev.end_ns)))
                     elif ev.name == LAUNCH:

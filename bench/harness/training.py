@@ -32,7 +32,7 @@ import numpy as np
 
 from bench.harness import generate as G
 from bench.harness import reference as REF
-from bench.harness import serving as SV
+from bench.harness import spec as SP
 from bench.harness import weights as WT
 
 
@@ -73,13 +73,15 @@ class TrainCell:
     """One training run: ``setup()``, ``window()``, then the readings."""
 
     def __init__(self, config: dict, traffic: dict, seed: int, *,
-                 tracer=None, feed=None):
+                 tracer=None, feed=None, base=SP.BENCH_DIR):
         self.config, self.traffic, self.seed = config, traffic, seed
         self.tracer = tracer            # (start, stop) callables or None
         # the batch of a step; the correctness tests swap in a broken one
         self.feed = feed or (lambda step: G.train_batch(
             traffic, config["vocab_size"], seed, step))
-        self.cfg = SV.model_config(config)
+        self.family = SP.load_family(config, base)
+        train_reference_of(self.family, config)
+        self.cfg = self.family.program_config(config)
         self.opt_cfg = traffic["optimizer"]
         self.real_tokens = int(sum(traffic["doc_lens"]))
         self.readings: dict = {}
@@ -231,9 +233,19 @@ def compare(readings: dict, ref: dict, floor: float = 1e-3) -> dict:
                        "left_out": sorted(set(ref["grad"]) - moved)}}
 
 
+def train_reference_of(family, config: dict):
+    """The family's reference model of ``config``, which has to train."""
+    m = family.Reference.from_config(config)
+    if not callable(getattr(m, "train_block", None)):
+        raise SP.SpecError(f"family {config.get('family', 'dense')!r} of "
+                           f"configuration {config.get('name')!r} has no "
+                           f"train_block: its reference cannot train")
+    return m
+
+
 def reference_readings(config: dict, traffic: dict, seed: int, prec="f32",
-                       feed=None) -> dict:
-    m = REF.Model.from_config(config)
+                       feed=None, base=SP.BENCH_DIR) -> dict:
+    m = train_reference_of(SP.load_family(config, base), config)
     return REF.train_reference(m, seed, check_batches(traffic, config, seed,
                                                       feed),
                                traffic["optimizer"], prec=prec)
@@ -243,10 +255,10 @@ def train_cell(cell, config, traffic, limits, seed, seconds, trace,
                t_process, chips, base, tracer=None, log=print):
     """One run of a training cell: the run record for bench/run.py."""
     from bench.harness import device as DV
-    from bench.harness import work as WK
 
     tc = TrainCell(config, traffic, seed,
-                   tracer=(tracer.start, tracer.stop) if tracer else None)
+                   tracer=(tracer.start, tracer.stop) if tracer else None,
+                   base=base)
     tc.setup()
     t_start, t_stop, steps, mets = tc.window(seconds)
     setup_s = t_start - t_process
@@ -260,13 +272,13 @@ def train_cell(cell, config, traffic, limits, seed, seconds, trace,
         f"tokens ({sum(-(-n // traffic['block']) * traffic['block'] for n in traffic['doc_lens'])} "
         f"packed), {values['train_tokens_per_s']:.1f} tokens/s, "
         f"{span / steps * 1e3:.3f} ms a step; set-up {setup_s:.3f} s")
-    rec = {"shape": WK.Shape.from_config(config), "trace": None,
+    rec = {"shape": tc.family.Shape.from_config(config), "trace": None,
            "traced_steps": [traffic["doc_lens"]] * tc.traced_steps}
     readings = tc.readings
     tc.release()
     if tracer is not None:
         rec["trace"] = tracer.reduce("bench.window")
-    ref = reference_readings(config, traffic, seed)
+    ref = reference_readings(config, traffic, seed, base=base)
     cmp = compare(readings, ref)
     log(f"reference: {cmp['detail']}")
     return {"values": values, "rec": rec, "attempted": steps,

@@ -1,6 +1,12 @@
 """A tiny benchmark tree for CPU tests: one configuration at toy widths
 and serving/training mixes sized to run in seconds, written under a
-temporary directory in the layout of bench/ and BENCHMARK.json."""
+temporary directory in the layout of bench/ and BENCHMARK.json.
+
+A new cell may add, each as a file of its own: a configuration
+(configs/<config>.json), a model family (families/<family>.py, named by
+the configuration's ``"family"``), a traffic mix (traffic/<traffic>.json),
+its limits (limits/<workload>.json), per-layer metrics
+(metrics/<metric>.py) and kernel families (kernels/<kernel>.json)."""
 
 from __future__ import annotations
 
@@ -51,11 +57,13 @@ TRAIN_LIMITS = {"grad_norm_gap_max": {"limit": 0.01},
 def write_tree(root: pathlib.Path, cells: dict, configs: dict,
                traffics: dict, limits: dict, metrics=("decode_packed_share",
                                                       )) -> pathlib.Path:
-    """A checkout-shaped tree: BENCHMARK.json plus bench/ data files. The
-    per-layer metric readers named in ``metrics`` are copied from bench/
-    under the cell-neutral names given. Returns the tree's bench dir."""
+    """A checkout-shaped tree: BENCHMARK.json plus bench/ data files and
+    the families of bench/families/. The per-layer metric readers named in
+    ``metrics`` are copied from bench/ under the cell-neutral names given.
+    Returns the tree's bench dir."""
     base = root / "bench"
-    for sub in ("configs", "traffic", "limits", "metrics", "kernels"):
+    for sub in ("configs", "families", "traffic", "limits", "metrics",
+                "kernels"):
         (base / sub).mkdir(parents=True, exist_ok=True)
     for name, cfg in configs.items():
         (base / "configs" / f"{name}.json").write_text(json.dumps(cfg))
@@ -64,8 +72,9 @@ def write_tree(root: pathlib.Path, cells: dict, configs: dict,
     for name, lim in limits.items():
         (base / "limits" / f"{name}.json").write_text(json.dumps(lim))
     shutil.copy(BENCH / "peaks.json", base / "peaks.json")
-    for p in (BENCH / "kernels").glob("*.json"):
-        shutil.copy(p, base / "kernels" / p.name)
+    for sub, pattern in (("kernels", "*.json"), ("families", "*.py")):
+        for p in (BENCH / sub).glob(pattern):
+            shutil.copy(p, base / sub / p.name)
     bench = {
         "command": ["python3", "bench/run.py"], "paths": ["bench"],
         "run_seconds": 2,

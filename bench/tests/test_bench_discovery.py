@@ -12,9 +12,16 @@ import pytest
 import bench_tiny as T
 from bench import run as R
 from bench.harness import generate as G
+from bench.harness import serving as SV
 from bench.harness import spec as SP
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
+PROBE_SRC = (pathlib.Path(__file__).resolve().parent
+             / "probe_family.py").read_text()
+# a tiny mixture of experts (4 experts, top-2) of the probe family, in
+# float32 so the program and the reference agree to rounding
+PROBE_MOE = dict(T.TINY_CONFIG, family="probe_moe", num_local_experts=4,
+                 num_experts_per_tok=2, torch_dtype="float32")
 
 
 def test_repo_benchmark_resolves_every_name():
@@ -71,6 +78,96 @@ def test_added_files_are_found_and_run(tmp_path):
     assert set(res["metrics"]) == {"setup_s", "itl_p95_ms"}
     assert list(res)[-1] == "checks"
     assert {p: p.read_bytes() for p in BENCH.rglob("*.py")} == before
+
+
+def _probe_tree(root: pathlib.Path, family_src: str) -> pathlib.Path:
+    """A tree whose one cell is of a family written only as new files:
+    the family, a metric that reads its Shape and one that reads an
+    engine counter."""
+    base = T.write_tree(root, {"probe-moe.probe_closed":
+                               ("probe-moe", "probe_closed")},
+                        {"probe-moe": PROBE_MOE},
+                        {"probe_closed": T.TINY_CLOSED},
+                        {"probe-moe.probe_closed": T.GAP_LIMIT})
+    (base / "families" / "probe_moe.py").write_text(family_src)
+    (base / "metrics" / "probe_routed.py").write_text(
+        "def read(rec):\n"
+        "    return float(rec['shape'].routed_expert_params)\n")
+    (base / "metrics" / "probe_prefill_tokens.py").write_text(
+        "def read(rec):\n"
+        "    return float(sum(r.counters.get('prefill_tokens', 0)\n"
+        "                     for r in rec['rounds']))\n")
+    return base
+
+
+@pytest.mark.parametrize("top_k,correct", [("config", True), ("1", False)])
+def test_a_family_added_as_files_runs(tmp_path, monkeypatch, top_k,
+                                      correct):
+    """A mixture-of-experts family, a new file under families/, runs
+    through run_cell with no harness file edited: correct against its own
+    float32 reference, and not correct when that reference routes each
+    token to one expert where the program routes it to two."""
+    before = {p: p.read_bytes() for p in BENCH.rglob("*.py")}
+    src = PROBE_SRC
+    if top_k != "config":
+        old = 'top_k=c["num_experts_per_tok"]'
+        assert src.count(old) == 1
+        src = src.replace(old, f"top_k={top_k}")
+    base = _probe_tree(tmp_path, src)
+    seen = {}
+    real = SV.serve_cell
+
+    def keep(*a, **kw):
+        seen.update(real(*a, **kw))
+        return seen
+
+    monkeypatch.setattr(SV, "serve_cell", keep)
+    res = R.run_cell("probe-moe.probe_closed", 2**31 + 77, 1.0, False,
+                     root=tmp_path, base=base)
+    assert res["correct"] is correct, res["checks"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+    family = SP.load_family(PROBE_MOE, base)
+    assert SV.ServeCell(PROBE_MOE, T.TINY_CLOSED, 1, 1.0,
+                        base=base).cfg.n_experts == 4
+    rec = seen["rec"]
+    assert isinstance(rec["shape"], family.Shape)
+    assert SP.metric_reader("probe_routed", base)(rec) == \
+        2 * 2 * 3 * 64 * 96
+    admitted = sum(sum(r.admitted) for r in rec["rounds"])
+    assert SP.metric_reader("probe_prefill_tokens", base)(rec) >= admitted
+    assert {p: p.read_bytes() for p in BENCH.rglob("*.py")} == before
+
+
+def test_a_family_without_train_block_refuses_training(tmp_path):
+    from bench.harness import training as TRN
+
+    base = _probe_tree(tmp_path, PROBE_SRC)
+    with pytest.raises(SP.SpecError, match="train_block"):
+        TRN.TrainCell(PROBE_MOE, T.TINY_TRAIN, 1, base=base)
+    with pytest.raises(SP.SpecError, match="no family file"):
+        SP.load_family(dict(PROBE_MOE, family="absent"), base)
+
+
+def test_round_counters_are_the_registry_change_over_the_round():
+    """Each window round carries the change of every engine counter over
+    that round; together they are the change over the window, and
+    ``packed`` is the packed-decode counter's."""
+    cell = SV.ServeCell(T.TINY_CONFIG, T.TINY_CLOSED, 2**31 + 3, 1.0)
+    cell.setup()
+    start = cell.engine.registry.snapshot()["counters"]
+    cell.window()
+    end = cell.engine.registry.snapshot()["counters"]
+    cell.release()
+    total = {}
+    for r in cell.rounds:
+        assert r.packed == (r.counters.get("decode_packed_launches", 0) > 0)
+        assert all(v > 0 for v in r.counters.values())
+        for k, v in r.counters.items():
+            total[k] = total.get(k, 0) + v
+    assert total == SV.counter_change(start, end)
+    assert total["decode_rounds"] > 0 and total["prefill_tokens"] > 0
+    assert SV.counter_change({"a": 1, "b": 2}, {"a": 1, "b": 5, "c": 1}) \
+        == {"b": 3, "c": 1}
 
 
 @pytest.mark.parametrize("name", ["serve_decode", "tiny_open"])

@@ -6,15 +6,20 @@ grouping, norm or activation would miss by O(1)."""
 
 from __future__ import annotations
 
+import json
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import bench_tiny as T
+import dense_golden as DG
 from bench.harness import generate as G
 from bench.harness import reference as REF
 from bench.harness import serving as SV
+from bench.harness import spec as SP
 from bench.harness import weights as WT
 from repro.kernels.tri_attn import ops as OPS
 from repro.models import model as MD
@@ -108,7 +113,7 @@ def test_weights_made_again_layer_by_layer():
     cfg = SV.model_config(config)
     params = SV.make_params(cfg, SEED)
     m = REF.Model.from_config(config)
-    again = WT.layer_weights(WT.base_key(SEED), 1, m.layer_shapes(),
+    again = WT.layer_weights(WT.base_key(SEED), 1, m.block_shapes(),
                              m.served_dtypes())
     served = params["layers"]["l0"]
     for path, value in again.items():
@@ -121,3 +126,33 @@ def test_weights_made_again_layer_by_layer():
         b = np.asarray(value)
         np.testing.assert_allclose(a, b, rtol=2.0 ** -8)
         assert np.mean(a != b) < 0.2
+
+
+def _same(got, want, where=""):
+    if isinstance(want, dict):
+        assert sorted(got) == sorted(want), where
+        for k in want:
+            _same(got[k], want[k], f"{where}/{k}")
+    elif isinstance(want, list):
+        assert len(got) == len(want), where
+        for i, (g, w) in enumerate(zip(got, want)):
+            _same(g, w, f"{where}[{i}]")
+    elif isinstance(want, int):
+        assert got == want, where
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=0,
+                                   err_msg=where)
+
+
+@pytest.mark.parametrize("name", sorted(DG.configs()))
+def test_dense_family_reads_as_recorded(name):
+    """Through the dense family, the reference's gaps and argmax, its
+    training norms and the work counts are those recorded before the
+    harness took families (bench/tests/data/dense_golden.json)."""
+    want = json.loads((pathlib.Path(__file__).resolve().parent / "data"
+                       / "dense_golden.json").read_text())[name]
+    config = DG.configs()[name]
+    fam = SP.load_family(config)
+    assert fam.Reference is REF.Model
+    _same(DG.readings(fam.Reference.from_config(config),
+                      fam.Shape.from_config(config)), want)

@@ -71,8 +71,22 @@ def test_kernel_families_match_their_ops_and_nothing_else():
         assert TRC.family_of(TRC.Op(r["op"], 0, 1), fam) == r["family"], \
             r["op"][:120]
     for f in fam.values():
-        assert callable(getattr(__import__("bench.harness.work",
-                                           fromlist=["x"]), f["work"]))
+        assert callable(SP.work_function(f["work"]))
+
+
+def test_work_function_takes_a_bare_name_or_a_module_path():
+    from bench.harness import readers
+    from bench.harness import work as WK
+
+    assert SP.work_function("packed_fwd") is WK.packed_fwd
+    assert SP.work_function("bench.harness.work:decode_attn") is \
+        WK.decode_attn
+    assert SP.work_function("bench.harness.readers:idle_share") is \
+        readers.idle_share
+    for bad in ("no_such_count", "bench.harness.work:Shape.nothing",
+                "bench.harness.no_such_module:f"):
+        with pytest.raises(SP.SpecError):
+            SP.work_function(bad)
 
 
 def test_self_time_of_nested_ops():
@@ -102,3 +116,75 @@ def test_clock_offset_pairs_programs_from_the_end():
     assert TRC.clock_offset(starts, launches) == 0
     assert TRC.clock_offset([100, 1100], [1500, 2600]) == 1500
     assert TRC.clock_offset([], [5]) == 0
+
+
+def _as_before(trace):
+    """The trace as the reduction read it when only ``SPANS`` were kept."""
+    return TRC.Trace(trace.ops, [s for s in trace.spans
+                                 if s[0] in TRC.SPANS], trace.devices,
+                     trace.offset_ns)
+
+
+def _unchanged(old: dict, new: dict):
+    for key in ("window_ns", "busy_ns", "kernel_ns", "top_ops"):
+        assert new[key] == old[key], key
+    for name, ns in old["busy_in_span_ns"].items():
+        assert new["busy_in_span_ns"][name] == ns
+        assert new["span_count"][name] == old["span_count"][name]
+    assert sum(new["idle_by_label"].values()) == \
+        sum(old["idle_by_label"].values())
+
+
+def test_spans_are_kept_by_prefix(small):
+    """Every engine.* and bench.* host span is kept, so a span added to
+    the engine is read with no edit; on the recorded trace, which opens
+    only names of SPANS, nothing moves."""
+    assert {n for n, _, _ in small.spans} == {
+        "bench.round", "engine.admit_batch", "engine.decode_round"}
+    lo, hi = _window(small)
+    fam = SP.kernel_families()
+    new = TRC.reduce(small, fam, lo, hi)
+    assert new == TRC.reduce(_as_before(small), fam, lo, hi)
+    rec = {"trace": dict(new, marks=3)}
+    from bench.harness import readers
+
+    assert readers.engine_host_ms_per_round(rec) is None
+    assert readers.host_syncs_per_round(rec) is None
+
+
+@pytest.fixture(scope="module")
+def phases():
+    return TRC.load(DATA / "trace_phases.xplane.pb")
+
+
+def test_phase_spans_are_read_and_old_readings_stay(phases):
+    """A v5e trace whose rounds open the engine's phase spans: they now
+    reach the reduction, which gives the same window, busy, kernel and
+    per-span readings of the old names as it did when it dropped them;
+    only the idle labels get finer, and the two engine metrics read it."""
+    from bench.harness import readers
+
+    names = {n for n, _, _ in phases.spans}
+    assert names >= {"engine.round", "engine.schedule", "engine.guard",
+                     "engine.sample", "engine.commit", "engine.to_host",
+                     "engine.admit_commit", "engine.decode_plan"}
+    assert names <= set(TRC.SPANS) | {
+        n for n in names if n.startswith(TRC.PREFIXES)}
+    lo, hi = _window(phases)
+    fam = SP.kernel_families()
+    old = TRC.reduce(_as_before(phases), fam, lo, hi)
+    new = TRC.reduce(phases, fam, lo, hi)
+    _unchanged(old, new)
+    assert new["kernel_ns"]["packed_fwd"]["count"] == 3
+    assert new["span_count"]["engine.round"] == 3
+    assert new["span_count"]["engine.to_host"] == 3
+    assert old["idle_by_label"].get("engine.sample", 0) == 0
+    # the round's host sleeps (5 ms of the 6 ms each round's phases
+    # sleep) fall under the phases that slept
+    assert new["idle_by_label"]["engine.sample"] > 3 * 1.5e6
+    engine_idle = sum(ns for n, ns in new["idle_by_label"].items()
+                      if n.startswith("engine."))
+    rec = {"trace": dict(new, marks=3)}
+    assert readers.engine_host_ms_per_round(rec) == engine_idle / 3 / 1e6
+    assert readers.engine_host_ms_per_round(rec) > 4.0
+    assert readers.host_syncs_per_round(rec) == 1.0
